@@ -200,6 +200,7 @@ def cross_topology_restore(tmp):
     import subprocess, sys, textwrap
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"    # 8 simulated host devices, never a chip
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src")),
          env.get("PYTHONPATH", "")])

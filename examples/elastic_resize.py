@@ -30,6 +30,7 @@ ENV = dict(os.environ)
 ENV["PYTHONPATH"] = os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", "src"))
 ENV["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+ENV["JAX_PLATFORMS"] = "cpu"    # 8 simulated host devices, never a chip
 
 CODE = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np, tempfile

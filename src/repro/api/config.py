@@ -64,7 +64,7 @@ class CodecPolicy(WireRecord):
     device-side encode+digest kernels ("off" default; "auto" enables on
     accelerator backends only; "on" forces the fused path — XLA-on-CPU
     without an accelerator). Restores are bit-identical either way; a
-    device failure falls back to the host codec per leaf. ``chunking``
+    device failure fails the dump. ``chunking``
     picks the chunker: "fixed" windows, or "cdc" content-defined
     boundaries that keep dedup alive across leaf reshaping.
 

@@ -13,9 +13,9 @@ overlaps the device->host transfer with the executor's chunk writes:
 leaves before blocking on the oldest transfer (a bounded deque — the
 double buffer), landing each result into a per-leaf Future the executor's
 ``do_leaf`` consumes in place of the host codec. On a serial engine the
-pump runs inline before the dump (correct, no overlap — the documented
-fallback), and any per-leaf device failure falls back to the host codec
-for that leaf instead of failing the dump.
+pump runs inline before the dump (correct, no overlap). A device failure
+fails the dump with the original error: there is no second codec to fall
+back to on a TPU, where the host codec runs the same kernels.
 
 Bit-identity contract: the stored buffer a landed Future carries is byte
 for byte what ``core.compression.encode_leaf`` would have produced — the
@@ -26,7 +26,6 @@ digest ("pmac32x2-v1"), which decode_leaf re-verifies.
 """
 from __future__ import annotations
 
-import logging
 from collections import deque
 from concurrent.futures import Future
 
@@ -35,10 +34,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.compression import CODEC_BLOCK, encode_leaf
+from repro.core.compression import CODEC_BLOCK
 from repro.kernels.ckpt_codec import ops
-
-log = logging.getLogger(__name__)
 
 DEVICE_CODEC_MODES = ("off", "auto", "on")
 # below this a leaf's dispatch overhead beats the fused win; encode on host
@@ -104,7 +101,8 @@ def encode_leaves(plan, source: dict, prev_host_tree: dict | None = None,
     stage uploads; on CPU backends upload is free). Returns {path: Future
     -> (stored np.ndarray, codec_meta)} covering the eligible leaves; the
     executor's do_leaf falls through to the host codec for every other
-    path. Failures degrade per leaf to the host codec, never fail the dump.
+    path. A failure is set on every future not yet landed; run inline,
+    it is also raised from here.
     """
     prev_host_tree = prev_host_tree or {}
     todo = [lp for lp in plan.leaves
@@ -124,37 +122,27 @@ def encode_leaves(plan, source: dict, prev_host_tree: dict | None = None,
         return ops.bf16_encode_digest(x, block=CODEC_BLOCK,
                                       interpret=interpret)
 
-    def fallback(lp, err):
-        log.warning("device codec: host fallback for %s: %r", lp.path, err)
-        fut = futs[lp.path]
-        try:
-            arr = np.asarray(source[lp.path])
-            prev = (np.asarray(prev_host_tree[lp.path])
-                    if lp.codec == "delta8" else None)
-            fut.set_result(encode_leaf(arr, lp.codec, prev))
-        except BaseException as e:      # pragma: no cover - double fault
-            fut.set_exception(e)
-
-    def land_one(pending):
+    def land_oldest(pending):
         lp, out = pending.popleft()
-        try:
-            res = _land(lp, out)
-        except Exception as e:
-            fallback(lp, e)
-            return
-        futs[lp.path].set_result(res)
+        futs[lp.path].set_result(_land(lp, out))
 
     def pump():
         pending = deque()
-        for lp in todo:
-            try:
+        try:
+            for lp in todo:
                 pending.append((lp, dispatch(lp)))
-            except Exception as e:
-                fallback(lp, e)
-            while len(pending) >= depth:
-                land_one(pending)
-        while pending:
-            land_one(pending)
+                while len(pending) >= depth:
+                    land_oldest(pending)
+            while pending:
+                land_oldest(pending)
+        except BaseException as e:
+            # a device failure fails the dump with the original error:
+            # every leaf still waiting on the stage gets it, so no chunk
+            # writer blocks on a result that will never land
+            for fut in futs.values():
+                if not fut.done():
+                    fut.set_exception(e)
+            raise
 
     started = executor.submit_cpu(pump) if executor is not None else None
     if started is None:

@@ -133,8 +133,8 @@ class CheckpointExecutor:
             if fut is not None:
                 # device stage: block on this leaf's landed result (the
                 # stage keeps the NEXT leaf's encode + transfer in flight
-                # while we chunk/write this one); any device failure was
-                # already degraded to a host encode inside the stage
+                # while we chunk/write this one); a device failure
+                # raises here and fails the dump
                 stored, codec_meta = fut.result()
                 raw_nbytes, orig_dtype, orig_shape = (
                     lp.nbytes, lp.dtype, list(lp.shape))

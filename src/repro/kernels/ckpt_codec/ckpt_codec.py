@@ -5,17 +5,31 @@ The dump path is pure memory streaming (read current + previous snapshot,
 write int8 + per-block scale): arithmetic intensity ~0.25 flop/byte, i.e.
 hard HBM-bandwidth-bound. The kernel's job is to keep the streams fused in
 one pass (x, prev -> q, scale, dirty) instead of XLA's 4+ materialized
-intermediates; blocks are sized to VMEM (default 64 KiB per operand tile).
+intermediates.
 
-Grid: 1D over blocks. Validated in interpret mode against ref.py, including
-the exact-zero (clean block) path that drives incremental dumps.
+Tiling. A codec block of ``blk`` elements (16384 in checkpoints) is viewed
+as a ``(blk // 128, 128)`` sublane x lane tile — (128, 128) for the
+default block, which satisfies the TPU's f32 (8, 128), bf16 (16, 128) and
+int8 (32, 128) tilings alike. One grid step takes ``GROUP`` consecutive
+blocks, ``[G, blk // 128, 128]``, so the per-step overhead is paid once
+per 512 KiB of f32 input instead of once per 64 KiB. Per-block scalars
+(scale, dirty flag, digest lanes) leave the kernel as lane-dense rows: an
+``[nblk, 128]`` output whose row ``i`` holds block ``i``'s value in every
+lane, written as one (G, 128) tile per step; the wrappers keep lane 0.
+The decode kernel takes its scales the same way. A grid that does not
+divide ``nblk`` ends in a partial step whose out-of-range rows are
+dropped on write.
+
+The stored layout does not depend on any of this: the wrappers return
+exactly the ``[nblk, blk]`` payload and ``[nblk]`` scalars of ref.py.
 
 The *_digest kernels fuse a per-block integrity digest (two uint32
 polynomial mult-acc lanes over the encoded payload — see ref.py) into the
-same pass, so dirty detection, quantization and digesting cost one read of
-HBM instead of three host passes. The digest weight table is an ordinary
-input with a constant index map: every grid step sees the same [2, blk]
-tile, resident in VMEM across the whole sweep.
+same pass. The lanes are computed in int32, whose wraparound multiply and
+add are bit-identical to uint32's, and bitcast to uint32 outside the
+kernel. The digest weight table is an ordinary input with a constant index
+map: every grid step sees the same [2, blk // 128, 128] tile, resident in
+VMEM across the whole sweep.
 """
 from __future__ import annotations
 
@@ -24,95 +38,165 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
+GROUP = 8          # codec blocks per grid step: one (8, 128) scalar tile
 
-def _encode_kernel(x_ref, p_ref, q_ref, s_ref, d_ref):
-    x = x_ref[...].astype(jnp.float32)
-    p = p_ref[...].astype(jnp.float32)
-    d = x - p
-    amax = jnp.max(jnp.abs(d))
+
+def _group(nblk: int) -> int:
+    return min(GROUP, nblk)
+
+
+def _tiled(a):
+    """[nblk, blk] -> [nblk, blk // 128, 128] (a free row-major view)."""
+    nblk, blk = a.shape
+    assert blk % LANES == 0, f"codec block {blk} is not a multiple of 128"
+    return a.reshape(nblk, blk // LANES, LANES)
+
+
+def _block_spec(g, rows):
+    return pl.BlockSpec((g, rows, LANES), lambda i: (i, 0, 0))
+
+
+def _row_spec(g):
+    return pl.BlockSpec((g, LANES), lambda i: (i, 0))
+
+
+def _weights_spec(rows):
+    return pl.BlockSpec((2, rows, LANES), lambda i: (0, 0, 0))
+
+
+def _rows(g, values):
+    """Per-block (1, 1) values -> one lane-dense (g, 128) tile, row j
+    holding values[j] in every lane."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (g, LANES), 0)
+    tile = jnp.zeros((g, LANES), values[0].dtype)
+    for j, v in enumerate(values):
+        tile = jnp.where(row == j, v, tile)
+    return tile
+
+
+def _lanes(units, w_ref):
+    """units: (rows, 128) int32 payload units of one block -> the two
+    (1, 1) int32 mult-acc lanes (wraparound)."""
+    h1 = jnp.sum(units * w_ref[0], keepdims=True)
+    h2 = jnp.sum(units * w_ref[1], keepdims=True)
+    return h1, h2
+
+
+def _quantize(x, p):
+    """One block's delta8 encode, the ref.py formulas: -> (q int32,
+    scale (1, 1) f32, dirty (1, 1) int32)."""
+    d = x.astype(jnp.float32) - p.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(d), keepdims=True)
     dirty = amax > 0.0
     scale = jnp.where(dirty, amax / 127.0, 0.0)
     inv = jnp.where(dirty, 1.0 / jnp.maximum(scale, 1e-30), 0.0)
-    q_ref[...] = jnp.clip(jnp.round(d * inv), -127, 127).astype(jnp.int8)
-    s_ref[0] = scale
-    d_ref[0] = dirty.astype(jnp.int32)
+    q = jnp.clip(jnp.round(d * inv), -127, 127).astype(jnp.int32)
+    return q, scale, dirty.astype(jnp.int32)
 
 
-def _decode_kernel(q_ref, s_ref, p_ref, x_ref):
-    x_ref[...] = (p_ref[...].astype(jnp.float32)
-                  + q_ref[...].astype(jnp.float32) * s_ref[0]
-                  ).astype(x_ref.dtype)
-
-
-def delta_encode_pallas(x, prev, *, interpret=False):
-    """x, prev: [nblk, blk] -> (q int8, scale f32 [nblk], dirty i32 [nblk])."""
-    nblk, blk = x.shape
-    out = pl.pallas_call(
-        _encode_kernel,
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0)),
-                  pl.BlockSpec((1, blk), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0)),
-                   pl.BlockSpec((1,), lambda i: (i,)),
-                   pl.BlockSpec((1,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((nblk, blk), jnp.int8),
-                   jax.ShapeDtypeStruct((nblk,), jnp.float32),
-                   jax.ShapeDtypeStruct((nblk,), jnp.int32)],
-        interpret=interpret,
-    )(x, prev)
-    q, s, d = out
-    return q, s, d > 0
-
-
-def _digest_of(units, w_ref):
-    """units: [1, blk] uint32 payload units inside a kernel; w_ref: the
-    [2, blk] weight tile. -> (h1, h2) uint32 scalars (wraparound)."""
-    u = units.astype(jnp.uint32)
-    h1 = jnp.sum(u * w_ref[0, :], dtype=jnp.uint32)
-    h2 = jnp.sum(u * w_ref[1, :], dtype=jnp.uint32)
-    return h1, h2
+def _encode_kernel(x_ref, p_ref, q_ref, s_ref, d_ref):
+    g = x_ref.shape[0]
+    scales, dirties = [], []
+    for j in range(g):
+        q, s, d = _quantize(x_ref[j], p_ref[j])
+        q_ref[j] = q.astype(jnp.int8)
+        scales.append(s)
+        dirties.append(d)
+    s_ref[...] = _rows(g, scales)
+    d_ref[...] = _rows(g, dirties)
 
 
 def _encode_digest_kernel(x_ref, p_ref, w_ref,
                           q_ref, s_ref, d_ref, h1_ref, h2_ref):
-    x = x_ref[...].astype(jnp.float32)
-    p = p_ref[...].astype(jnp.float32)
-    d = x - p
-    amax = jnp.max(jnp.abs(d))
-    dirty = amax > 0.0
-    scale = jnp.where(dirty, amax / 127.0, 0.0)
-    inv = jnp.where(dirty, 1.0 / jnp.maximum(scale, 1e-30), 0.0)
-    qi = jnp.clip(jnp.round(d * inv), -127, 127).astype(jnp.int32)
-    q_ref[...] = qi.astype(jnp.int8)
-    s_ref[0] = scale
-    d_ref[0] = dirty.astype(jnp.int32)
-    h1, h2 = _digest_of((qi & 0xFF)[0], w_ref)
-    h1_ref[0] = h1
-    h2_ref[0] = h2
+    g = x_ref.shape[0]
+    scales, dirties, h1s, h2s = [], [], [], []
+    for j in range(g):
+        q, s, d = _quantize(x_ref[j], p_ref[j])
+        q_ref[j] = q.astype(jnp.int8)
+        h1, h2 = _lanes(q & 0xFF, w_ref)
+        scales.append(s)
+        dirties.append(d)
+        h1s.append(h1)
+        h2s.append(h2)
+    s_ref[...] = _rows(g, scales)
+    d_ref[...] = _rows(g, dirties)
+    h1_ref[...] = _rows(g, h1s)
+    h2_ref[...] = _rows(g, h2s)
+
+
+def _bf16_bits(y):
+    """bf16 values -> their 16-bit patterns as int32. Widening to f32 is
+    exact and puts the bf16 pattern in the high half, so no 16-bit
+    integer op is needed."""
+    wide = jax.lax.bitcast_convert_type(y.astype(jnp.float32), jnp.int32)
+    return jax.lax.shift_right_logical(wide, 16)
 
 
 def _bf16_digest_kernel(x_ref, w_ref, y_ref, h1_ref, h2_ref):
-    y = x_ref[...].astype(jnp.bfloat16)
-    y_ref[...] = y
-    bits = jax.lax.bitcast_convert_type(y, jnp.uint16)
-    h1, h2 = _digest_of(bits[0], w_ref)
-    h1_ref[0] = h1
-    h2_ref[0] = h2
+    g = x_ref.shape[0]
+    h1s, h2s = [], []
+    for j in range(g):
+        y = x_ref[j].astype(jnp.bfloat16)
+        y_ref[j] = y
+        h1, h2 = _lanes(_bf16_bits(y), w_ref)
+        h1s.append(h1)
+        h2s.append(h2)
+    h1_ref[...] = _rows(g, h1s)
+    h2_ref[...] = _rows(g, h2s)
 
 
 def _digest_kernel(x_ref, w_ref, h1_ref, h2_ref):
-    bits = jax.lax.bitcast_convert_type(
-        x_ref[...].astype(jnp.float32), jnp.uint32)
-    h1, h2 = _digest_of(bits[0], w_ref)
-    h1_ref[0] = h1
-    h2_ref[0] = h2
+    g = x_ref.shape[0]
+    h1s, h2s = [], []
+    for j in range(g):
+        bits = jax.lax.bitcast_convert_type(
+            x_ref[j].astype(jnp.float32), jnp.int32)
+        h1, h2 = _lanes(bits, w_ref)
+        h1s.append(h1)
+        h2s.append(h2)
+    h1_ref[...] = _rows(g, h1s)
+    h2_ref[...] = _rows(g, h2s)
 
 
-def _scalar_specs(nblk):
-    return [pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,))], \
-           [jax.ShapeDtypeStruct((nblk,), jnp.uint32),
-            jax.ShapeDtypeStruct((nblk,), jnp.uint32)]
+def _decode_kernel(q_ref, s_ref, p_ref, x_ref):
+    for j in range(q_ref.shape[0]):
+        x_ref[j] = (p_ref[j].astype(jnp.float32)
+                    + q_ref[j].astype(jnp.int32).astype(jnp.float32)
+                    * s_ref[j:j + 1, :]).astype(x_ref.dtype)
+
+
+def _row_shape(nblk, dtype):
+    return jax.ShapeDtypeStruct((nblk, LANES), dtype)
+
+
+def _weights(weights, rows):
+    """[2, blk] uint32 -> [2, rows, 128] int32 (same bits)."""
+    return jax.lax.bitcast_convert_type(
+        weights, jnp.int32).reshape(2, rows, LANES)
+
+
+def _u32(h):
+    """Lane-dense int32 digest rows -> [nblk] uint32."""
+    return jax.lax.bitcast_convert_type(h[:, 0], jnp.uint32)
+
+
+def delta_encode_pallas(x, prev, *, interpret=False):
+    """x, prev: [nblk, blk] -> (q int8, scale f32 [nblk], dirty bool
+    [nblk])."""
+    nblk, blk = x.shape
+    g, rows = _group(nblk), blk // LANES
+    q, s, d = pl.pallas_call(
+        _encode_kernel,
+        grid=(pl.cdiv(nblk, g),),
+        in_specs=[_block_spec(g, rows), _block_spec(g, rows)],
+        out_specs=[_block_spec(g, rows), _row_spec(g), _row_spec(g)],
+        out_shape=[jax.ShapeDtypeStruct((nblk, rows, LANES), jnp.int8),
+                   _row_shape(nblk, jnp.float32),
+                   _row_shape(nblk, jnp.int32)],
+        interpret=interpret,
+    )(_tiled(x), _tiled(prev))
+    return q.reshape(nblk, blk), s[:, 0], d[:, 0] > 0
 
 
 def delta_encode_digest_pallas(x, prev, weights, *, interpret=False):
@@ -120,23 +204,21 @@ def delta_encode_digest_pallas(x, prev, weights, *, interpret=False):
     x, prev: [nblk, blk]; weights: [2, blk] uint32.
     -> (q int8, scale f32 [nblk], dirty bool [nblk], h1, h2 uint32 [nblk])."""
     nblk, blk = x.shape
-    hspecs, hshapes = _scalar_specs(nblk)
-    out = pl.pallas_call(
+    g, rows = _group(nblk), blk // LANES
+    q, s, d, h1, h2 = pl.pallas_call(
         _encode_digest_kernel,
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0)),
-                  pl.BlockSpec((1, blk), lambda i: (i, 0)),
-                  pl.BlockSpec((2, blk), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0)),
-                   pl.BlockSpec((1,), lambda i: (i,)),
-                   pl.BlockSpec((1,), lambda i: (i,))] + hspecs,
-        out_shape=[jax.ShapeDtypeStruct((nblk, blk), jnp.int8),
-                   jax.ShapeDtypeStruct((nblk,), jnp.float32),
-                   jax.ShapeDtypeStruct((nblk,), jnp.int32)] + hshapes,
+        grid=(pl.cdiv(nblk, g),),
+        in_specs=[_block_spec(g, rows), _block_spec(g, rows),
+                  _weights_spec(rows)],
+        out_specs=[_block_spec(g, rows)] + [_row_spec(g)] * 4,
+        out_shape=[jax.ShapeDtypeStruct((nblk, rows, LANES), jnp.int8),
+                   _row_shape(nblk, jnp.float32),
+                   _row_shape(nblk, jnp.int32),
+                   _row_shape(nblk, jnp.int32),
+                   _row_shape(nblk, jnp.int32)],
         interpret=interpret,
-    )(x, prev, weights)
-    q, s, d, h1, h2 = out
-    return q, s, d > 0, h1, h2
+    )(_tiled(x), _tiled(prev), _weights(weights, rows))
+    return q.reshape(nblk, blk), s[:, 0], d[:, 0] > 0, _u32(h1), _u32(h2)
 
 
 def bf16_encode_digest_pallas(x, weights, *, interpret=False):
@@ -144,44 +226,50 @@ def bf16_encode_digest_pallas(x, weights, *, interpret=False):
     x: [nblk, blk] f32; weights: [2, blk] uint32.
     -> (y bf16 [nblk, blk], h1, h2 uint32 [nblk])."""
     nblk, blk = x.shape
-    hspecs, hshapes = _scalar_specs(nblk)
-    return pl.pallas_call(
+    g, rows = _group(nblk), blk // LANES
+    y, h1, h2 = pl.pallas_call(
         _bf16_digest_kernel,
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0)),
-                  pl.BlockSpec((2, blk), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0))] + hspecs,
-        out_shape=[jax.ShapeDtypeStruct((nblk, blk), jnp.bfloat16)]
-        + hshapes,
+        grid=(pl.cdiv(nblk, g),),
+        in_specs=[_block_spec(g, rows), _weights_spec(rows)],
+        out_specs=[_block_spec(g, rows), _row_spec(g), _row_spec(g)],
+        out_shape=[jax.ShapeDtypeStruct((nblk, rows, LANES), jnp.bfloat16),
+                   _row_shape(nblk, jnp.int32),
+                   _row_shape(nblk, jnp.int32)],
         interpret=interpret,
-    )(x, weights)
+    )(_tiled(x), _weights(weights, rows))
+    return y.reshape(nblk, blk), _u32(h1), _u32(h2)
 
 
 def digest_blocks_pallas(x, weights, *, interpret=False):
     """Digest-only sweep over raw fp32 blocks (dirty-classification /
     verification without re-encoding). -> (h1, h2 uint32 [nblk])."""
     nblk, blk = x.shape
-    hspecs, hshapes = _scalar_specs(nblk)
-    return pl.pallas_call(
+    g, rows = _group(nblk), blk // LANES
+    h1, h2 = pl.pallas_call(
         _digest_kernel,
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0)),
-                  pl.BlockSpec((2, blk), lambda i: (0, 0))],
-        out_specs=hspecs,
-        out_shape=hshapes,
+        grid=(pl.cdiv(nblk, g),),
+        in_specs=[_block_spec(g, rows), _weights_spec(rows)],
+        out_specs=[_row_spec(g), _row_spec(g)],
+        out_shape=[_row_shape(nblk, jnp.int32),
+                   _row_shape(nblk, jnp.int32)],
         interpret=interpret,
-    )(x, weights)
+    )(_tiled(x), _weights(weights, rows))
+    return _u32(h1), _u32(h2)
 
 
 def delta_decode_pallas(q, scale, prev, *, interpret=False):
+    """q int8 [nblk, blk], scale f32 [nblk], prev [nblk, blk] ->
+    prev + q * scale, in prev's dtype."""
     nblk, blk = q.shape
-    return pl.pallas_call(
+    g, rows = _group(nblk), blk // LANES
+    srows = jnp.broadcast_to(scale.astype(jnp.float32)[:, None],
+                             (nblk, LANES))
+    x = pl.pallas_call(
         _decode_kernel,
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0)),
-                  pl.BlockSpec((1,), lambda i: (i,)),
-                  pl.BlockSpec((1, blk), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, blk), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblk, blk), prev.dtype),
+        grid=(pl.cdiv(nblk, g),),
+        in_specs=[_block_spec(g, rows), _row_spec(g), _block_spec(g, rows)],
+        out_specs=_block_spec(g, rows),
+        out_shape=jax.ShapeDtypeStruct((nblk, rows, LANES), prev.dtype),
         interpret=interpret,
-    )(q, scale, prev)
+    )(_tiled(q), srows, _tiled(prev))
+    return x.reshape(nblk, blk)

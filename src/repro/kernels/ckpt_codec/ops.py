@@ -34,7 +34,7 @@ from repro.kernels.ckpt_codec.ckpt_codec import (bf16_encode_digest_pallas,
                                                  delta_encode_pallas,
                                                  digest_blocks_pallas)
 
-BLOCK = 16384  # fp32 elements per block = 64 KiB VMEM tile per operand
+BLOCK = 16384  # fp32 elements per codec block: one (128, 128) kernel tile
 
 DIGEST_ALG = "pmac32x2-v1"
 _R1 = np.uint32(0x01000193)   # FNV-1 prime (odd -> invertible mod 2^32)

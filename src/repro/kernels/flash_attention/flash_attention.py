@@ -101,9 +101,6 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, softcap=0.0,
     vf = jnp.moveaxis(v, 2, 1).reshape(B * KV, Skv, hd)
 
     from jax.experimental.pallas import tpu as pltpu
-    # jax<=0.4.x spells it TPUCompilerParams; newer jax renamed it.
-    compiler_params_cls = getattr(pltpu, "TPUCompilerParams", None) \
-        or pltpu.CompilerParams
     kern = functools.partial(
         _kernel, scale=hd ** -0.5, causal=causal, window=window,
         softcap=softcap, blk_q=blk_q, blk_k=blk_k, n_kv=n_kv, q_off=q_off)
@@ -122,7 +119,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, softcap=0.0,
         scratch_shapes=[pltpu.VMEM((blk_q,), jnp.float32),
                         pltpu.VMEM((blk_q,), jnp.float32),
                         pltpu.VMEM((blk_q, hd), jnp.float32)],
-        compiler_params=compiler_params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

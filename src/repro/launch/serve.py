@@ -19,6 +19,7 @@ import numpy as np
 
 from repro import configs
 from repro.core import Checkpointer, serve_meta
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import LM
 from repro.serving import ServeEngine
 
@@ -37,6 +38,7 @@ def main(argv=None):
     ap.add_argument("--stop-after-ckpt", action="store_true")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = configs.get_tiny(args.arch) if args.tiny \
         else configs.get_config(args.arch)

@@ -33,6 +33,7 @@ from repro.api import (CheckpointSession, DumpRequest, MigrateRequest,
                        SessionConfig)
 from repro.core import EXIT_CHECKPOINTED, PreemptionHandler, train_meta
 from repro.data import DataIterator, TokenDataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import LM
 from repro.optim import OptConfig
 from repro.training.train_loop import (abstract_train_state,
@@ -115,6 +116,7 @@ def main(argv=None):
     ap.add_argument("--step-delay", type=float, default=0.0,
                     help="artificial per-step delay (fault-injection tests)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = build_cfg(args)
     lm = LM(cfg)

@@ -1,9 +1,7 @@
 """Device-side codec stage (core/device_codec.py): the fused encode+digest
 path must be a drop-in for the host codec — byte-identical stored buffers,
-per-leaf fallback on any device failure, digest verification that trips on
+device failures that fail the dump, digest verification that trips on
 corrupted payloads, and mode/eligibility gating."""
-import logging
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -107,21 +105,27 @@ def test_corrupted_payload_trips_digest_on_decode():
         decode_leaf(bad, "delta8", meta, prev)
 
 
-def test_device_failure_falls_back_to_host_codec(monkeypatch, caplog):
+def test_device_failure_propagates_out_of_dump(monkeypatch, tmp_path):
+    """No host fallback: an injected device failure fails the dump with
+    the original error and commits no image."""
     x, prev = leaf_pair(3)
 
     def boom(*a, **kw):
         raise RuntimeError("injected device fault")
 
     monkeypatch.setattr(ops, "delta_encode_digest", boom)
-    with caplog.at_level(logging.WARNING, logger="repro.core.device_codec"):
-        futs = dc.encode_leaves(delta_plan(x, prev), {"opt/m/w": x},
-                                {"opt/m/w": prev})
-        stored, meta = futs["opt/m/w"].result()
-    assert any("host fallback" in r.message for r in caplog.records)
-    stored_host, meta_host = encode_leaf(x, "delta8", prev)
-    np.testing.assert_array_equal(stored, stored_host)
-    assert meta == meta_host                  # host meta: no device digest
+    with pytest.raises(RuntimeError, match="injected device fault"):
+        dc.encode_leaves(delta_plan(x, prev), {"opt/m/w": x},
+                         {"opt/m/w": prev})["opt/m/w"].result()
+
+    t1, t2 = tree_pair()
+    sess = CheckpointSession(SessionConfig(
+        root=str(tmp_path / "ck"),
+        codec=CodecPolicy(optimizer="delta8", device="on")))
+    first = sess.dump(DumpRequest(state=t1, step=1))   # no baseline yet
+    with pytest.raises(RuntimeError, match="injected device fault"):
+        sess.dump(DumpRequest(state=t2, step=2))
+    assert sess.registry.latest()["image_id"] == first.image_id
 
 
 # ------------------------------------------------------------- end to end

@@ -86,6 +86,27 @@ def test_fused_bf16_digest_pallas_equals_ref(nblk, blk):
     assert bool(jnp.all(h1p == h1r)) and bool(jnp.all(h2p == h2r))
 
 
+def test_partial_last_grid_step_matches_ref():
+    """nblk not a multiple of the kernels' block group: the last grid step
+    is partial and its out-of-range rows must not leak into the result."""
+    nblk, blk = 11, 256
+    k1, k2 = jax.random.split(jax.random.PRNGKey(4))
+    x = jax.random.normal(k1, (nblk, blk), jnp.float32)
+    prev = x + 0.01 * jax.random.normal(k2, (nblk, blk), jnp.float32)
+    w = ops.digest_weights(blk)
+    got = delta_encode_digest_pallas(x, prev, w, interpret=True)
+    want = delta_encode_digest_ref(x, prev, w)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and bool(jnp.all(a == b))
+    yp, h1p, h2p = bf16_encode_digest_pallas(x, w, interpret=True)
+    yr, h1r, h2r = bf16_encode_digest_ref(x, w)
+    assert bool(jnp.all(yp == yr)) and bool(jnp.all(h1p == h1r))
+    assert bool(jnp.all(h2p == h2r))
+    xp = delta_decode_pallas(got[0], got[1], prev, interpret=True)
+    xr = delta_decode_ref(want[0], want[1], prev)
+    assert float(jnp.abs(xp - xr).max()) < 1e-6
+
+
 def test_digest_blocks_pallas_equals_ref():
     x = jax.random.normal(jax.random.PRNGKey(3), (5, 256), jnp.float32)
     w = ops.digest_weights(256)
