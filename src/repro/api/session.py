@@ -26,15 +26,15 @@ dumper and its registry: gc must update the same in-memory chunk index the
 dump path dedups against."""
 from __future__ import annotations
 
-import time
-
-import jax
+import functools
 
 from repro.api.config import SessionConfig
 from repro.api.requests import (DumpReceipt, DumpRequest, MigrateRequest,
                                 MigrationTicket, RestoreRequest,
                                 RestoreResult)
+from repro.core import tracing
 from repro.core.async_engine import AsyncCheckpointer as _AsyncEngine
+from repro.core.dump import capture
 from repro.core.dump import dump as _dump
 from repro.core.dump import flatten_with_paths, host_tree_by_path
 from repro.core.executor import CheckpointExecutor, get_default_executor
@@ -42,6 +42,18 @@ from repro.core.plan import DumpPlan, plan_dump
 from repro.core.registry import Registry
 from repro.core.restore import restore as _restore
 from repro.core.storage import as_tier
+
+
+def _phase(name: str):
+    """Run an engine method as the span ``name`` (core/tracing.py); the
+    span stays on ``self._span``, whose length the typed receipts report."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(self, tree, *, step, **kw):
+            with tracing.span(name, step=int(step)) as self._span:
+                return fn(self, tree, step=step, **kw)
+        return run
+    return wrap
 
 
 def _step_of(image_id: str) -> int | None:
@@ -96,6 +108,7 @@ class CheckpointSession:
         self._prev_image = None  # image id _prev_host is the content of
         self._tracker = None    # lazy DirtyLeafTracker (pre-dump rounds)
         self._orch = None       # lazy MigrationOrchestrator
+        self._span = None       # the newest engine call's span
         self._installed = False
         self._closed = False
 
@@ -159,7 +172,6 @@ class CheckpointSession:
             raise TypeError(f"dump() takes a DumpRequest, got "
                             f"{type(request).__name__} — build one, or use "
                             f"the legacy save() shim")
-        t0 = time.monotonic()
         if request.mode == "pre_dump":
             out = self.pre_dump(request.state, step=request.step,
                                 meta=request.meta,
@@ -167,7 +179,7 @@ class CheckpointSession:
             return DumpReceipt(step=int(request.step), mode="pre_dump",
                                committed=True, image_id=out["image_id"],
                                stats=out["stats"],
-                               duration_s=time.monotonic() - t0)
+                               duration_s=self._span.duration_s)
         if request.mode == "async":
             if not self.config.async_dumps.enabled:
                 raise RuntimeError("async dumps are disabled by this "
@@ -176,13 +188,13 @@ class CheckpointSession:
                             meta=request.meta, topology=request.topology)
             return DumpReceipt(step=int(request.step), mode="async",
                                committed=False,
-                               duration_s=time.monotonic() - t0)
+                               duration_s=self._span.duration_s)
         out = self.save(request.state, step=request.step, meta=request.meta,
                         topology=request.topology)
         return DumpReceipt(step=int(request.step), mode="sync",
                            committed=True, image_id=out["image_id"],
                            stats=out["stats"],
-                           duration_s=time.monotonic() - t0)
+                           duration_s=self._span.duration_s)
 
     def wait(self) -> list:
         """Barrier: every async dump enqueued since the last barrier is
@@ -314,6 +326,13 @@ class CheckpointSession:
                                executor=self.executor)
         return self._tracker.reuse_for(digests), digests
 
+    def _retain(self):
+        """Retention policy, then pool gc, as a ``ckpt.gc`` span."""
+        with tracing.span("ckpt.gc"):
+            self.registry.retain(self.keep_last, self.keep_every)
+            self.registry.gc()
+
+    @_phase("ckpt.save")
     def save(self, tree, *, step: int, meta: dict | None = None,
              topology: dict | None = None) -> dict:
         """Raw-dict sync dump (the engine under DumpRequest(mode="sync")):
@@ -334,7 +353,7 @@ class CheckpointSession:
             # drained results: the next wait() still owes them to the
             # caller
             self._drained.extend(self._async.wait())
-        host = jax.device_get(tree)   # one capture, shared with the baseline
+        host = capture(tree)   # one capture, shared with the baseline
         # residual-dump path: after pre-dump rounds, digest-unchanged
         # leaves re-emit their cached records — the freeze window pays
         # only for the dirty set (plus the classification pass itself)
@@ -350,10 +369,10 @@ class CheckpointSession:
         if digests is not None:
             self._tracker.update(digests, out["records"], out["image_id"],
                                  pre_dump=False)
-        self.registry.retain(self.keep_last, self.keep_every)
-        self.registry.gc()
+        self._retain()
         return out
 
+    @_phase("ckpt.pre_dump")
     def pre_dump(self, tree, *, step: int, meta: dict | None = None,
                  topology: dict | None = None) -> dict:
         """One iterative pre-copy round (CRIU `criu pre-dump`): commit a
@@ -376,7 +395,7 @@ class CheckpointSession:
             self._tracker = DirtyLeafTracker()
         if self._async is not None:
             self._drained.extend(self._async.wait())   # causal parents
-        host = jax.device_get(tree)
+        host = capture(tree)
         pairs = flatten_with_paths(host)
         digests = digest_pairs(pairs, executor=self.executor)
         reuse = self._tracker.reuse_for(digests)
@@ -409,13 +428,13 @@ class CheckpointSession:
             self._prev_host = host_tree_by_path(host)
             self._prev_step = step
             self._prev_image = out["image_id"]
-        self.registry.retain(self.keep_last, self.keep_every)
-        self.registry.gc()
+        self._retain()
         out["stats"]["predump_round"] = rnd
         out["stats"]["leaves_dirty"] = len(pairs) - len(reuse)
         out["stats"]["leaves_clean"] = len(reuse)
         return out
 
+    @_phase("ckpt.save_async")
     def save_async(self, tree, *, step: int, meta: dict | None = None,
                    topology: dict | None = None):
         """Raw-dict async dump (the engine under DumpRequest(mode=
@@ -439,8 +458,8 @@ class CheckpointSession:
         kw = self._save_kw(step, meta, topology, with_parent=False)
         baseline_step = self._prev_step
         baseline_image = self._prev_image
-        host = jax.device_get(tree)   # one capture: the job's input and
-        #                               the next call's delta baseline
+        host = capture(tree)   # one capture: the job's input and
+        #                        the next call's delta baseline
         if self.codec_policy is not None and self.incremental:
             # mirror save(): job N's delta baseline (kw's prev_host_tree,
             # the tree of the PRECEDING save call) must equal the content
@@ -456,8 +475,7 @@ class CheckpointSession:
     def _wait_raw(self) -> list:
         if self._async is not None:
             out, self._drained = self._drained + self._async.wait(), []
-            self.registry.retain(self.keep_last, self.keep_every)
-            self.registry.gc()
+            self._retain()
             return out
         return []
 

@@ -12,9 +12,9 @@ backpressure)."""
 from __future__ import annotations
 
 import threading
+import time
 
-import jax
-
+from repro.core.dump import capture
 from repro.core.dump import dump as _dump_fn
 from repro.core.executor import CheckpointExecutor, get_default_executor
 
@@ -39,7 +39,9 @@ class AsyncCheckpointer:
                    baseline_step: int | None = None,
                    baseline_image: str | None = None, **kw):
         """Synchronously captures (device_get) then submits the write job.
-        Blocks only if max_pending dumps are already in flight.
+        Blocks only if max_pending dumps are already in flight. The job's
+        ``ckpt.dump`` span carries ``queued_s``: submit to dump start (the
+        wait on the ordered lane and the run-time parent resolution).
 
         resolve_parent: re-resolve the incremental parent link when the job
         RUNS (the previous ordered dump has committed by then) instead of
@@ -66,7 +68,8 @@ class AsyncCheckpointer:
                                 kw["step"], baseline_image=baseline_image)
                     out = _dump_fn(host_tree, self.root,
                                    replicas=self.replicas,
-                                   executor=self._ex, **kw)
+                                   executor=self._ex,
+                                   submitted_at=submitted, **kw)
                     with self._lock:
                         self._results.append(out)
                 except Exception as e:     # surfaced on wait()
@@ -76,9 +79,10 @@ class AsyncCheckpointer:
                 self._slots.release()
 
         try:
-            host_tree = jax.device_get(tree)   # donation-safe: host copy
+            host_tree = capture(tree)   # donation-safe: host copy
             with self._lock:
                 self._pending = [f for f in self._pending if not f.done()]
+                submitted = time.perf_counter()
                 self._pending.append(self._ex.submit(job))
         except BaseException:
             self._slots.release()

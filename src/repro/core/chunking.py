@@ -27,9 +27,11 @@ Two chunkers share that contract:
 from __future__ import annotations
 
 import bisect
+import time
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.integrity import sha256
 
 CHUNK_BYTES = 4 << 20  # 4 MiB
@@ -61,10 +63,13 @@ def chunk_views(data, chunk_bytes: int = CHUNK_BYTES):
     Empty input still yields one (empty) chunk so every leaf has at least
     one addressable chunk."""
     mv = memoryview(data)
+    t0 = time.perf_counter()
     out = []
     for off in range(0, max(len(mv), 1), chunk_bytes):
         part = mv[off:off + chunk_bytes]
         out.append((sha256(part), part))
+    tracing.count("chunk_hash", len(out), mv.nbytes,
+                  time.perf_counter() - t0)
     return out
 
 
@@ -124,10 +129,14 @@ def cdc_chunk_views(data, avg_bytes: int = CHUNK_BYTES):
     if len(mv) == 0:
         return [(sha256(mv), mv)]
     out, last = [], 0
-    for cut in cdc_cut_points(mv, avg_bytes):
+    cuts = cdc_cut_points(mv, avg_bytes)
+    t0 = time.perf_counter()
+    for cut in cuts:
         part = mv[last:cut]
         out.append((sha256(part), part))
         last = cut
+    tracing.count("chunk_hash", len(out), mv.nbytes,
+                  time.perf_counter() - t0)
     return out
 
 

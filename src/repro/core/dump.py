@@ -15,7 +15,7 @@ import json
 import jax
 import numpy as np
 
-from repro.core import chunking, manifest
+from repro.core import chunking, manifest, tracing
 from repro.core import device_codec as device_codec_mod
 from repro.core.executor import CheckpointExecutor, get_default_executor
 from repro.core.plan import plan_dump
@@ -38,6 +38,16 @@ def flatten_with_paths(tree) -> list:
     return out
 
 
+def capture(tree):
+    """Device -> host copy of a state, the quiesce point (``device_get``
+    blocks on all in-flight work), recorded as a ``ckpt.capture`` span."""
+    with tracing.span("ckpt.capture") as sp:
+        host = jax.device_get(tree)
+        sp.set(bytes=sum(getattr(a, "nbytes", 0)
+                         for a in jax.tree_util.tree_leaves(host)))
+    return host
+
+
 def dump(tree, root, *, step: int, image_id: str | None = None,
          meta: dict | None = None, parent: str | None = None,
          codec_policy=None, prev_host_tree: dict | None = None,
@@ -46,7 +56,8 @@ def dump(tree, root, *, step: int, image_id: str | None = None,
          process_index: int = 0, num_processes: int = 1,
          executor: CheckpointExecutor | None = None,
          reuse_records: dict | None = None,
-         device_codec: str = "off", device_source=None) -> dict:
+         device_codec: str = "off", device_source=None,
+         submitted_at: float | None = None) -> dict:
     """Returns {"image_id", "stats", "records"}. ``prev_host_tree``
     (path->np array) enables delta8; ``parent`` links the incremental
     chain; ``reuse_records`` re-emits cached records for digest-proven
@@ -57,66 +68,75 @@ def dump(tree, root, *, step: int, image_id: str | None = None,
     through the fused device encode+digest stage (core/device_codec.py),
     double-buffered against the host chunk writes; ``device_source`` is
     the original (possibly device-resident) tree so encode reads HBM
-    directly — defaults to ``tree``."""
-    tier = as_tier(root)
-    replicas = [as_tier(r) for r in replicas]
-    ex = executor or get_default_executor()
+    directly — defaults to ``tree``. The whole body is a ``ckpt.dump``
+    span; an async job passes ``submitted_at`` (``time.perf_counter`` at
+    submit), and the span records ``queued_s``, submit to start."""
+    with tracing.span("ckpt.dump", rss=True, step=int(step)) as sp:
+        if submitted_at is not None:
+            sp.set(queued_s=sp.t0 - submitted_at)
+        tier = as_tier(root)
+        replicas = [as_tier(r) for r in replicas]
+        ex = executor or get_default_executor()
 
-    host = jax.device_get(tree)          # quiesce + device->host capture
-    leaves = flatten_with_paths(host)
-    plan = plan_dump(leaves, step=step, image_id=image_id, parent=parent,
-                     codec_policy=codec_policy,
-                     prev_host_tree=prev_host_tree, chunk_bytes=chunk_bytes,
-                     chunking=chunking_mode,
-                     process_index=process_index,
-                     num_processes=num_processes,
-                     reuse_records=reuse_records)
+        host = capture(tree)
+        leaves = flatten_with_paths(host)
+        plan = plan_dump(leaves, step=step, image_id=image_id,
+                         parent=parent, codec_policy=codec_policy,
+                         prev_host_tree=prev_host_tree,
+                         chunk_bytes=chunk_bytes,
+                         chunking=chunking_mode,
+                         process_index=process_index,
+                         num_processes=num_processes,
+                         reuse_records=reuse_records)
+        sp.set(image_id=plan.image_id)
 
-    encoded = None
-    if device_codec_mod.resolve_mode(device_codec):
-        src = dict(flatten_with_paths(
-            device_source if device_source is not None else host))
-        encoded = device_codec_mod.encode_leaves(
-            plan, src, prev_host_tree, ex)
+        encoded = None
+        if device_codec_mod.resolve_mode(device_codec):
+            src = dict(flatten_with_paths(
+                device_source if device_source is not None else host))
+            encoded = device_codec_mod.encode_leaves(
+                plan, src, prev_host_tree, ex)
 
-    arrays = {p: np.asarray(a) for p, a in leaves}
-    # the writer guard spans probe->write->commit: a concurrent gc on the
-    # SAME tier object (sessions sharing a mem://, remote:// or
-    # cache+remote:// URI) waits here instead of reaping chunks this dump
-    # has written but not yet referenced from a committed manifest
-    with tier.writer():
-        out = ex.run_dump(plan, arrays, tier, replicas,
-                          prev_host_tree=prev_host_tree, encoded=encoded)
+        arrays = {p: np.asarray(a) for p, a in leaves}
+        # the writer guard spans probe->write->commit: a concurrent gc on
+        # the SAME tier object (sessions sharing a mem://, remote:// or
+        # cache+remote:// URI) waits here instead of reaping chunks this
+        # dump has written but not yet referenced from a committed manifest
+        with tier.writer():
+            out = ex.run_dump(plan, arrays, tier, replicas,
+                              prev_host_tree=prev_host_tree, encoded=encoded)
 
-        man = manifest.build(plan.image_id, step=step, leaves=out["records"],
-                             meta=meta or {}, parent=parent,
-                             env=manifest.env_fingerprint(),
-                             topology=topology)
-        journal = tier.ref_journal()
-        if journal is not None and num_processes == 1:
-            # refcount journal entry lands BEFORE the manifest (both
-            # inside the writer guard): a crash between the two leaves an
-            # orphan ref (bounded leak, swept later), never a committed
-            # manifest whose chunks a peer job's gc may reap
-            journal.publish(
-                plan.image_id,
-                {h for rec in out["records"] for h in rec["chunks"]},
-                manifest_rel=tier.manifest_path(plan.image_id))
-        if num_processes > 1:
-            part = f"images/{plan.image_id}/manifest.part{process_index}.json"
-            tier.write_bytes(part, manifest.to_json(man))
-            if process_index == 0:
-                merge_parts(tier, plan.image_id, num_processes,
-                            replicas=replicas)
-        else:
-            blob = manifest.to_json(man)
-            tier.write_bytes(tier.manifest_path(plan.image_id), blob,
-                             atomic=True)
-            for r in replicas:
-                r.write_bytes(r.manifest_path(plan.image_id), blob,
-                              atomic=True)
-    return {"image_id": plan.image_id, "stats": out["stats"],
-            "records": man["leaves"]}
+            man = manifest.build(plan.image_id, step=step,
+                                 leaves=out["records"],
+                                 meta=meta or {}, parent=parent,
+                                 env=manifest.env_fingerprint(),
+                                 topology=topology)
+            journal = tier.ref_journal()
+            if journal is not None and num_processes == 1:
+                # refcount journal entry lands BEFORE the manifest (both
+                # inside the writer guard): a crash between the two leaves
+                # an orphan ref (bounded leak, swept later), never a
+                # committed manifest whose chunks a peer job's gc may reap
+                journal.publish(
+                    plan.image_id,
+                    {h for rec in out["records"] for h in rec["chunks"]},
+                    manifest_rel=tier.manifest_path(plan.image_id))
+            if num_processes > 1:
+                part = (f"images/{plan.image_id}/"
+                        f"manifest.part{process_index}.json")
+                tier.write_bytes(part, manifest.to_json(man))
+                if process_index == 0:
+                    merge_parts(tier, plan.image_id, num_processes,
+                                replicas=replicas)
+            else:
+                blob = manifest.to_json(man)
+                tier.write_bytes(tier.manifest_path(plan.image_id), blob,
+                                 atomic=True)
+                for r in replicas:
+                    r.write_bytes(r.manifest_path(plan.image_id), blob,
+                                  atomic=True)
+        return {"image_id": plan.image_id, "stats": out["stats"],
+                "records": man["leaves"]}
 
 
 def merge_parts(tier: Tier, image_id: str, num_processes: int, replicas=()):

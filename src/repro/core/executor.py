@@ -21,6 +21,13 @@ Restore pipeline: leaf tasks on the cpu pool, chunk reads fanned out on the
 io pool, with a (image_id, path) memo so delta8 parent leaves are fetched +
 decoded once per chain instead of once per referencing leaf.
 
+Tracing (core/tracing.py): a dump's leaf work runs inside a
+``ckpt.dump.chunks`` span and a restore's inside ``ckpt.restore.chunks``;
+pool tasks run under a copy of the submitter's context, so their counters
+(``serialize``, ``chunk_hash``, ``chunk_write``, ``fsync``; ``chunk_read``,
+``chunk_verify``, ``decode``) reach that span. A dump's ``stats`` are read
+off its span's counters (``DUMP_STATS``).
+
 ``serial=True`` runs the identical plan inline on the calling thread with
 per-chunk existence probes — the seed engine's behavior, kept as the
 baseline for benchmarks/ckpt_throughput.py --compare and as a debugging
@@ -29,13 +36,27 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from concurrent.futures import Future, ThreadPoolExecutor
 
 import numpy as np
 
-from repro.core import chunking
+from repro.core import chunking, tracing
 from repro.core.compression import decode_leaf, encode_leaf
 from repro.core.integrity import CorruptionError, read_chunk_verified
+
+# out["stats"] of a dump -> (counter, field) on its ckpt.dump.chunks span
+DUMP_STATS = {
+    "bytes_raw": ("leaf", "bytes"),           # every leaf, before its codec
+    "bytes_stored": ("store", "bytes"),       # chunks handed to the tier
+    "bytes_deduped": ("dedup", "bytes"),      # already pooled or claimed
+    "chunks": ("chunk", "count"),             # chunks the records reference
+    "chunks_deduped": ("dedup", "count"),
+    "leaves_reused": ("reuse", "count"),      # pre-dump records re-emitted
+    "bytes_reused": ("reuse", "bytes"),
+    "leaves_device": ("device_encode", "count"),
+    "chunks_reuploaded": ("reupload", "count"),  # index hits a peer reaped
+}
 
 
 class CheckpointExecutor:
@@ -76,14 +97,9 @@ class CheckpointExecutor:
             tier.enable_chunk_index()
             for r in replicas:
                 r.enable_chunk_index()
-        stats = {"bytes_raw": 0, "bytes_stored": 0, "bytes_deduped": 0,
-                 "chunks": 0, "chunks_deduped": 0,
-                 "leaves_reused": 0, "bytes_reused": 0,
-                 "leaves_device": 0, "chunks_reuploaded": 0}
         crossjob = bool(getattr(tier, "shared_chunks", False))
         upload_delta = getattr(tier, "upload_delta", None)
         encoded = encoded or {}
-        stats_lock = threading.Lock()
         claimed: set = set()        # intra-dump first-writer-wins
         claim_lock = threading.Lock()
         prev_host_tree = prev_host_tree or {}
@@ -115,13 +131,11 @@ class CheckpointExecutor:
             except (FileNotFoundError, KeyError, OSError):
                 return None    # chunk vanished between probe and heal (a
                 #                foreign gc) — re-encode, don't fail the dump
-            with stats_lock:
-                stats["bytes_raw"] += lp.nbytes
-                stats["chunks"] += len(rec["chunks"])
-                stats["chunks_deduped"] += len(rec["chunks"])
-                stats["bytes_deduped"] += int(rec["nbytes"])
-                stats["leaves_reused"] += 1
-                stats["bytes_reused"] += int(rec["nbytes"])
+            n, nbytes = len(rec["chunks"]), int(rec["nbytes"])
+            tracing.count("leaf", nbytes=lp.nbytes)
+            tracing.count("chunk", n, nbytes)
+            tracing.count("dedup", n, nbytes)
+            tracing.count("reuse", nbytes=nbytes)
             return dict(rec)
 
         def do_leaf(lp):
@@ -138,15 +152,16 @@ class CheckpointExecutor:
                 stored, codec_meta = fut.result()
                 raw_nbytes, orig_dtype, orig_shape = (
                     lp.nbytes, lp.dtype, list(lp.shape))
-                with stats_lock:
-                    stats["leaves_device"] += 1
+                tracing.count("device_encode", nbytes=raw_nbytes)
             else:
                 arr = np.asarray(arrays[lp.path])
                 prev = prev_host_tree.get(lp.path) if lp.use_prev else None
                 stored, codec_meta = encode_leaf(arr, lp.codec, prev)
                 raw_nbytes, orig_dtype, orig_shape = (
                     arr.nbytes, str(arr.dtype), list(arr.shape))
+            t0 = time.perf_counter()
             data = chunking.leaf_to_bytes(np.asarray(stored))
+            tracing.count("serialize", 1, len(data), time.perf_counter() - t0)
             views = chunking.chunk_stream(data, plan.chunk_bytes,
                                           plan.chunking)
             rec = chunking.leaf_record(
@@ -165,9 +180,7 @@ class CheckpointExecutor:
                 # are re-uploaded below instead of silently skipped
                 confirmed = tier.verify_chunks(present)
                 if len(confirmed) != len(present):
-                    with stats_lock:
-                        stats["chunks_reuploaded"] += \
-                            len(present) - len(confirmed)
+                    tracing.count("reupload", len(present) - len(confirmed))
                 present = confirmed
             to_write, deduped_bytes = [], 0
             with claim_lock:
@@ -190,9 +203,9 @@ class CheckpointExecutor:
                     # one delta batch per leaf: absent chunks travel as
                     # batched parts on the transfer lanes (the io slot
                     # just shepherds the batch)
-                    futs = [self._io.submit(upload_delta, to_write)]
+                    futs = [tracing.submit(self._io, upload_delta, to_write)]
                 else:
-                    futs = [self._io.submit(tier.write_chunk, h, v)
+                    futs = [tracing.submit(self._io, tier.write_chunk, h, v)
                             for h, v in to_write]
                 for r in replicas:
                     # batched probe per replica too: don't fan out a
@@ -200,24 +213,27 @@ class CheckpointExecutor:
                     # (write_chunk still dedups the benign race where
                     # two leaves submit the same absent chunk)
                     rpresent = r.has_chunks({h for h, _ in views})
-                    futs += [self._io.submit(r.write_chunk, h, v)
+                    futs += [tracing.submit(self._io, r.write_chunk, h, v)
                              for h, v in views if h not in rpresent]
                 for f in futs:
                     f.result()   # propagate the first write error
 
-            with stats_lock:
-                stats["bytes_raw"] += raw_nbytes
-                stats["chunks"] += len(views)
-                stats["chunks_deduped"] += len(views) - len(to_write)
-                stats["bytes_deduped"] += deduped_bytes
-                stats["bytes_stored"] += sum(len(v) for _, v in to_write)
+            tracing.count("leaf", nbytes=raw_nbytes)
+            tracing.count("chunk", len(views), len(data))
+            tracing.count("dedup", len(views) - len(to_write), deduped_bytes)
+            tracing.count("store", len(to_write),
+                          sum(len(v) for _, v in to_write))
             return rec
 
-        if self._cpu is None:
-            records = [do_leaf(lp) for lp in plan.leaves]
-        else:
-            futs = [self._cpu.submit(do_leaf, lp) for lp in plan.leaves]
-            records = [f.result() for f in futs]
+        with tracing.span("ckpt.dump.chunks",
+                          leaves=len(plan.leaves)) as sp:
+            if self._cpu is None:
+                records = [do_leaf(lp) for lp in plan.leaves]
+            else:
+                futs = [tracing.submit(self._cpu, do_leaf, lp)
+                        for lp in plan.leaves]
+                records = [f.result() for f in futs]
+            stats = {k: sp.counter(c)[f] for k, (c, f) in DUMP_STATS.items()}
         return {"records": records, "stats": stats}
 
     # --------------------------------------------------------------- restore
@@ -236,8 +252,9 @@ class CheckpointExecutor:
             bad = []
             uniq = list(dict.fromkeys(rec["chunks"]))
             if self._io is not None and len(uniq) > 1:
-                pref = {h: self._io.submit(read_chunk_verified, tier,
-                                           replicas, h, iid) for h in uniq}
+                pref = {h: tracing.submit(self._io, read_chunk_verified,
+                                          tier, replicas, h, iid)
+                        for h in uniq}
 
                 def fetch(h):
                     return pref[h].result()
@@ -252,11 +269,14 @@ class CheckpointExecutor:
                     bad.append(h)
                     return b""
 
+            blobs = {h: read(h) for h in rec["chunks"]}  # waits on reads
+            t0 = time.perf_counter()
             stored = None
             try:
-                stored = chunking.assemble_leaf(rec, read)
+                stored = chunking.assemble_leaf(rec, blobs.__getitem__)
             except AssertionError:
                 pass
+            spent = time.perf_counter() - t0
             if bad or stored is None:
                 raise CorruptionError(iid, bad or [path])
 
@@ -268,7 +288,11 @@ class CheckpointExecutor:
                 # forever on its own memo future
                 assert pid != iid, f"cyclic parent chain at {iid}"
                 prev = resolve(pid, path)
-            return decode_leaf(stored, rec["codec"], rec["codec_meta"], prev)
+            t0 = time.perf_counter()
+            out = decode_leaf(stored, rec["codec"], rec["codec_meta"], prev)
+            tracing.count("decode", 1, out.nbytes,
+                          spent + time.perf_counter() - t0)
+            return out
 
         def resolve(iid, path):
             key = (iid, path)
@@ -294,12 +318,14 @@ class CheckpointExecutor:
         plan's top image. Raises CorruptionError on unrepairable chunks."""
         resolve = self.make_leaf_resolver(plan, tier, replicas)
         top = plan.manifests[plan.image_id]["leaves"]
-        if self._cpu is None:
-            return {r["path"]: resolve(plan.image_id, r["path"])
+        with tracing.span("ckpt.restore.chunks", leaves=len(top)):
+            if self._cpu is None:
+                return {r["path"]: resolve(plan.image_id, r["path"])
+                        for r in top}
+            futs = {r["path"]: tracing.submit(self._cpu, resolve,
+                                              plan.image_id, r["path"])
                     for r in top}
-        futs = {r["path"]: self._cpu.submit(resolve, plan.image_id,
-                                            r["path"]) for r in top}
-        return {p: f.result() for p, f in futs.items()}
+            return {p: f.result() for p, f in futs.items()}
 
     # ------------------------------------------------------------- utility
     def map_cpu(self, fn, items) -> list:
@@ -309,7 +335,7 @@ class CheckpointExecutor:
         items = list(items)
         if self._cpu is None:
             return [fn(x) for x in items]
-        return [f.result() for f in [self._cpu.submit(fn, x)
+        return [f.result() for f in [tracing.submit(self._cpu, fn, x)
                                      for x in items]]
 
     def submit_cpu(self, fn, *args) -> Future | None:
@@ -319,7 +345,7 @@ class CheckpointExecutor:
         (lazy prefetch), so callers never touch the private pools."""
         if self._cpu is None:
             return None
-        return self._cpu.submit(fn, *args)
+        return tracing.submit(self._cpu, fn, *args)
 
     # ------------------------------------------------------- transfer lanes
     def submit_transfer(self, fn, *args) -> Future | None:
@@ -335,7 +361,7 @@ class CheckpointExecutor:
             if self._xfer is None:
                 self._xfer = ThreadPoolExecutor(
                     self._xfer_workers, thread_name_prefix="ckpt-xfer")
-        return self._xfer.submit(fn, *args)
+        return tracing.submit(self._xfer, fn, *args)
 
     # ----------------------------------------------------------- async lane
     def submit(self, fn) -> Future:

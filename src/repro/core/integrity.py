@@ -9,6 +9,9 @@ from __future__ import annotations
 import hashlib
 import json
 import logging
+import time
+
+from repro.core import tracing
 
 log = logging.getLogger(__name__)
 
@@ -18,14 +21,20 @@ def sha256(data) -> str:
 
 
 def read_chunk_verified(tier, replicas, h: str, image_id: str) -> bytes:
-    """Content-addressed read with verification + replica repair."""
+    """Content-addressed read with verification + replica repair. Counts
+    ``chunk_read`` and ``chunk_verify`` (core/tracing.py) per source tried."""
     sources = [tier] + list(replicas)
     for k, src in enumerate(sources):
+        t0 = time.perf_counter()
         try:
             data = src.read_chunk(h)
         except FileNotFoundError:
             continue
-        if sha256(data) == h:
+        t1 = time.perf_counter()
+        tracing.count("chunk_read", 1, len(data), t1 - t0)
+        ok = sha256(data) == h
+        tracing.count("chunk_verify", 1, len(data), time.perf_counter() - t1)
+        if ok:
             if k > 0:  # repair the primary from the replica (overwrite the
                 # corrupt file — bypass the content-addressed dedup check)
                 tier.write_bytes(tier.chunk_path(h), data)
@@ -47,18 +56,21 @@ def tree_digest(pairs) -> str:
     values give the same digest no matter what mesh the tree lives on (or
     lived on), which is exactly the invariant a cross-topology migration
     must preserve. ``pairs`` is {path: array} or an iterable of
-    (path, array)."""
+    (path, array). Recorded as a ``ckpt.tree_digest`` span."""
     import numpy as np
     if isinstance(pairs, dict):
         pairs = pairs.items()
-    h = hashlib.sha256()
-    for path, arr in sorted(pairs, key=lambda kv: kv[0]):
-        a = np.asarray(arr)
-        h.update(path.encode())
-        h.update(str(a.dtype).encode())
-        h.update(repr(a.shape).encode())
-        h.update(np.ascontiguousarray(a).tobytes())
-    return h.hexdigest()
+    with tracing.span("ckpt.tree_digest") as sp:
+        h, nbytes = hashlib.sha256(), 0
+        for path, arr in sorted(pairs, key=lambda kv: kv[0]):
+            a = np.asarray(arr)
+            h.update(path.encode())
+            h.update(str(a.dtype).encode())
+            h.update(repr(a.shape).encode())
+            h.update(np.ascontiguousarray(a).tobytes())
+            nbytes += a.nbytes
+        sp.set(bytes=nbytes)
+        return h.hexdigest()
 
 
 class CorruptionError(RuntimeError):

@@ -36,12 +36,12 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import time
 from typing import Any
 
 import jax
 
-from repro.core.dump import flatten_with_paths
+from repro.core import tracing
+from repro.core.dump import capture, flatten_with_paths
 from repro.core.elastic import plan_topology_change, reshard
 from repro.core.integrity import CorruptionError, tree_digest
 from repro.core.preempt import EXIT_CHECKPOINTED, PreemptionHandler
@@ -254,44 +254,46 @@ class MigrationOrchestrator:
         """The preempt path, start to durable: quiesce -> drain -> dump with
         migration record -> wait. Returns EXIT_CHECKPOINTED for the caller
         to sys.exit() with (the orchestrator never exits by itself — tests
-        and multi-stage launchers need the control back)."""
-        t0 = time.monotonic()
-        if iterator is not None and hasattr(iterator, "stop_prefetch"):
-            iterator.stop_prefetch()
-        # drain in-flight async dumps first: they are this image's
-        # incremental ancestors and gc must never race their chunks
-        self.ckpt.wait()
-        if data_state is None and iterator is not None:
-            data_state = iterator.state()
-        host = jax.device_get(state)     # quiesce point: one capture shared
-        pairs = flatten_with_paths(host)  # by digest and dump
-        # the digest proves the restored bytes ARE the dumped bytes; a
-        # lossy codec policy (delta8/bf16 optimizer state) breaks that
-        # identity by design, so record no digest rather than make every
-        # lossy migration image fail verification on resume
-        digest = tree_digest(pairs) \
-            if getattr(self.ckpt, "codec_policy", None) is None else None
-        if step is None:
-            step = int(dict(pairs)["step"])
-        rec = self.build_manifest(step=step, data_state=data_state,
-                                  state_digest=digest, rng=rng)
-        meta = train_meta(arch=self.arch or "unknown", step=step,
-                          data_state=data_state or {}, opt_cfg=opt_cfg,
-                          extra=meta_extra)
-        if meta_extra and "serve_plane" in meta_extra:
-            # a serving plane migrated through the trainer path: the
-            # image must announce itself so restorers rebuild sessions
-            meta["job_kind"] = "serve"
-            meta["serve_plane"] = meta_extra["serve_plane"]
-            if "prefetch_hint" in meta_extra:
-                meta["prefetch_hint"] = meta_extra["prefetch_hint"]
-        meta[MIGRATION_META_KEY] = rec.to_meta()
-        out = self.ckpt.save(host, step=step, meta=meta,
-                             topology=_topology_of(self.mesh, self.topology))
-        self.ckpt.wait()                 # idempotent; async engines drain
+        and multi-stage launchers need the control back). The whole body
+        is a ``ckpt.migrate`` span; ``migrate_latency_s`` is its length."""
+        with tracing.span("ckpt.migrate", rss=True) as sp:
+            if iterator is not None and hasattr(iterator, "stop_prefetch"):
+                iterator.stop_prefetch()
+            # drain in-flight async dumps first: they are this image's
+            # incremental ancestors and gc must never race their chunks
+            self.ckpt.wait()
+            if data_state is None and iterator is not None:
+                data_state = iterator.state()
+            host = capture(state)      # quiesce point: one capture shared
+            pairs = flatten_with_paths(host)  # by digest and dump
+            # the digest proves the restored bytes ARE the dumped bytes; a
+            # lossy codec policy (delta8/bf16 optimizer state) breaks that
+            # identity by design, so record no digest rather than make
+            # every lossy migration image fail verification on resume
+            digest = tree_digest(pairs) \
+                if getattr(self.ckpt, "codec_policy", None) is None else None
+            if step is None:
+                step = int(dict(pairs)["step"])
+            rec = self.build_manifest(step=step, data_state=data_state,
+                                      state_digest=digest, rng=rng)
+            meta = train_meta(arch=self.arch or "unknown", step=step,
+                              data_state=data_state or {}, opt_cfg=opt_cfg,
+                              extra=meta_extra)
+            if meta_extra and "serve_plane" in meta_extra:
+                # a serving plane migrated through the trainer path: the
+                # image must announce itself so restorers rebuild sessions
+                meta["job_kind"] = "serve"
+                meta["serve_plane"] = meta_extra["serve_plane"]
+                if "prefetch_hint" in meta_extra:
+                    meta["prefetch_hint"] = meta_extra["prefetch_hint"]
+            meta[MIGRATION_META_KEY] = rec.to_meta()
+            out = self.ckpt.save(
+                host, step=step, meta=meta,
+                topology=_topology_of(self.mesh, self.topology))
+            self.ckpt.wait()             # idempotent; async engines drain
         self.last_migration = rec
         self.last_image_id = out["image_id"]
-        self.migrate_latency_s = time.monotonic() - t0
+        self.migrate_latency_s = sp.duration_s
         self.predump_rounds_run = 0   # a later migration pre-copies afresh
         log.info("migrated: image %s at step %d (%s) in %.3fs",
                  out["image_id"], step, rec.reason, self.migrate_latency_s)
@@ -356,65 +358,67 @@ def resume(root, *, target_struct=None, shardings=None, mesh=None,
     fully materializes (state.materialize() — CorruptionError on
     mismatch, exactly like the eager path, just deferred);
     target_struct/shardings don't apply to a tree that isn't there yet —
-    materialize() first, then cast/place."""
+    materialize() first, then cast/place. The whole body is a
+    ``ckpt.restore`` span."""
     from repro.core.restore import restore as _restore
 
-    if mesh is not None and (host_count is None or dp_degree is None):
-        topo = _topology_of(mesh, None)
-        host_count = host_count or topo["host_count"]
-        dp_degree = dp_degree or topo["dp_degree"]
+    with tracing.span("ckpt.restore", rss=True):
+        if mesh is not None and (host_count is None or dp_degree is None):
+            topo = _topology_of(mesh, None)
+            host_count = host_count or topo["host_count"]
+            dp_degree = dp_degree or topo["dp_degree"]
 
-    if lazy:
-        if target_struct is not None or shardings is not None:
-            raise ValueError(
-                "lazy restore serves raw host leaves on fault; "
-                "target_struct/shardings apply after materialize() — "
-                "restore eagerly, or cast/device_put the materialized "
-                "tree yourself")
-        from repro.core.lazy import lazy_restore
-        tree, man, server = lazy_restore(
-            root, image_id, replicas=replicas, executor=executor,
-            prefetch_order=prefetch_order,
-            allow_env_mismatch=allow_env_mismatch)
-        if verify_digest:
-            # deferred bit-identity: the server checks this digest when
-            # the tree fully materializes (LazyState.materialize /
-            # LeafServer.verify_tree_digest) — the lazy analogue of the
-            # eager pre-placement check below
-            server.expected_digest = \
-                MigrationManifest.from_image(man).state_digest
-        pairs = None
-    else:
-        tree, man, pairs = _restore(root, image_id,
-                                    target_struct=target_struct,
-                                    replicas=replicas, executor=executor,
-                                    allow_env_mismatch=allow_env_mismatch,
-                                    with_pairs=True)
-    rec = MigrationManifest.from_image(man)
+        if lazy:
+            if target_struct is not None or shardings is not None:
+                raise ValueError(
+                    "lazy restore serves raw host leaves on fault; "
+                    "target_struct/shardings apply after materialize() — "
+                    "restore eagerly, or cast/device_put the materialized "
+                    "tree yourself")
+            from repro.core.lazy import lazy_restore
+            tree, man, server = lazy_restore(
+                root, image_id, replicas=replicas, executor=executor,
+                prefetch_order=prefetch_order,
+                allow_env_mismatch=allow_env_mismatch)
+            if verify_digest:
+                # deferred bit-identity: the server checks this digest when
+                # the tree fully materializes (LazyState.materialize /
+                # LeafServer.verify_tree_digest) — the lazy analogue of the
+                # eager pre-placement check below
+                server.expected_digest = \
+                    MigrationManifest.from_image(man).state_digest
+            pairs = None
+        else:
+            tree, man, pairs = _restore(root, image_id,
+                                        target_struct=target_struct,
+                                        replicas=replicas, executor=executor,
+                                        allow_env_mismatch=allow_env_mismatch,
+                                        with_pairs=True)
+        rec = MigrationManifest.from_image(man)
 
-    plan = plan_topology_change(
-        {**dataclasses.asdict(rec), "data": rec.data},
-        new_host_count=host_count, new_dp_size=dp_degree,
-        global_batch=global_batch)
+        plan = plan_topology_change(
+            {**dataclasses.asdict(rec), "data": rec.data},
+            new_host_count=host_count, new_dp_size=dp_degree,
+            global_batch=global_batch)
 
-    digest_ok: bool | None = None
-    if lazy:
-        verify_digest = False     # nothing to digest until leaves arrive
-    if verify_digest and rec.state_digest:
-        got = tree_digest(pairs)     # raw decoded bytes, pre-cast/pre-place
-        digest_ok = got == rec.state_digest
-        if not digest_ok:
-            raise CorruptionError(man["image_id"],
-                                  [f"state digest {got[:12]} != recorded "
-                                   f"{rec.state_digest[:12]}"])
-    if plan["changed"]:
-        log.info("topology change on resume of %s: %s", man["image_id"],
-                 plan["changes"])
-    if shardings is not None:
-        tree = reshard(tree, shardings)
-    return ResumeReport(state=tree, manifest=man, migration=rec,
-                        topology_changed=plan["changed"],
-                        changes=plan["changes"],
-                        host_count=plan["host_count"],
-                        dp_degree=plan["dp_degree"], data=plan["data"],
-                        digest_verified=digest_ok)
+        digest_ok: bool | None = None
+        if lazy:
+            verify_digest = False     # nothing to digest until leaves arrive
+        if verify_digest and rec.state_digest:
+            got = tree_digest(pairs)   # raw decoded bytes, pre-cast/place
+            digest_ok = got == rec.state_digest
+            if not digest_ok:
+                raise CorruptionError(man["image_id"],
+                                      [f"state digest {got[:12]} != recorded "
+                                       f"{rec.state_digest[:12]}"])
+        if plan["changed"]:
+            log.info("topology change on resume of %s: %s", man["image_id"],
+                     plan["changes"])
+        if shardings is not None:
+            tree = reshard(tree, shardings)
+        return ResumeReport(state=tree, manifest=man, migration=rec,
+                            topology_changed=plan["changed"],
+                            changes=plan["changes"],
+                            host_count=plan["host_count"],
+                            dp_degree=plan["dp_degree"], data=plan["data"],
+                            digest_verified=digest_ok)

@@ -14,7 +14,7 @@ import logging
 
 import jax
 
-from repro.core import manifest
+from repro.core import manifest, tracing
 from repro.core.executor import CheckpointExecutor, get_default_executor
 from repro.core.plan import plan_restore
 from repro.core.storage import as_tier
@@ -93,11 +93,14 @@ def restore(root, image_id: str | None = None, *, target_struct=None,
         if missing:
             raise KeyError(f"checkpoint lacks leaves: {missing[:5]}")
         leaves = []
-        for p, (_, want) in zip(paths, flat[0]):
-            arr = pairs[p]
-            if tuple(arr.shape) != tuple(want.shape):
-                raise ValueError(f"{p}: shape {arr.shape} != {want.shape}")
-            leaves.append(arr.astype(want.dtype))
+        with tracing.span("ckpt.cast") as sp:
+            for p, (_, want) in zip(paths, flat[0]):
+                arr = pairs[p]
+                if tuple(arr.shape) != tuple(want.shape):
+                    raise ValueError(
+                        f"{p}: shape {arr.shape} != {want.shape}")
+                leaves.append(arr.astype(want.dtype))
+            sp.set(bytes=sum(a.nbytes for a in leaves))
         tree = jax.tree_util.tree_unflatten(flat[1], leaves)
     else:
         tree = _unflatten_paths(pairs)

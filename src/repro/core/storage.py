@@ -29,6 +29,8 @@ import threading
 import time
 from contextlib import contextmanager
 
+from repro.core import tracing
+
 _LOCK_INIT = threading.Lock()
 
 
@@ -272,7 +274,11 @@ class LocalDirTier(Tier):
     fsync modes: True (every file — strongest), "commit" (only commit-point
     writes, i.e. manifests; chunk durability relies on FS write-back
     ordering/journal barriers — the usual production trade), False (none;
-    tests / throwaway tiers)."""
+    tests / throwaway tiers).
+
+    Each write counts (core/tracing.py) ``fsync`` for ``os.fsync`` and
+    ``chunk_write`` (pool chunks) or ``file_write`` (manifests, journal)
+    for the open, write and rename around it."""
 
     def __init__(self, root: str, fsync=True, write_latency_s: float = 0.0):
         self.root = root
@@ -287,16 +293,24 @@ class LocalDirTier(Tier):
     def write_bytes(self, rel: str, data, atomic: bool = False):
         if self.write_latency_s:
             time.sleep(self.write_latency_s)
+        t0 = time.perf_counter()
         p = self._p(rel)
         os.makedirs(os.path.dirname(p), exist_ok=True)
         tmp = p + f".tmp.{os.getpid()}.{threading.get_ident()}"
         do_sync = self.fsync is True or (self.fsync == "commit" and atomic)
+        synced = 0.0
         with open(tmp, "wb") as f:
             f.write(data)
             if do_sync:
                 f.flush()
+                t1 = time.perf_counter()
                 os.fsync(f.fileno())
+                synced = time.perf_counter() - t1
+                tracing.count("fsync", 1, len(data), synced)
         os.replace(tmp, p)  # atomic on POSIX
+        tracing.count("chunk_write" if rel.startswith("chunks/")
+                      else "file_write", 1, len(data),
+                      time.perf_counter() - t0 - synced)
 
     def read_bytes(self, rel: str) -> bytes:
         with open(self._p(rel), "rb") as f:
