@@ -214,8 +214,9 @@ class LeafServer:
         restore path produces before device placement, just later."""
         if not self.expected_digest:
             return None
-        from repro.core.integrity import CorruptionError, tree_digest
-        got = tree_digest({p: self.get(p) for p in self._records})
+        from repro.core.integrity import CorruptionError, tree_digest_like
+        got = tree_digest_like({p: self.get(p) for p in self._records},
+                               self.expected_digest)
         if got != self.expected_digest:
             raise CorruptionError(
                 self.plan.image_id,

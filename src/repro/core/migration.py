@@ -43,7 +43,8 @@ import jax
 from repro.core import tracing
 from repro.core.dump import capture, flatten_with_paths
 from repro.core.elastic import plan_topology_change, reshard
-from repro.core.integrity import CorruptionError, tree_digest
+from repro.core.integrity import (CorruptionError, tree_digest,
+                                  tree_digest_like)
 from repro.core.preempt import EXIT_CHECKPOINTED, PreemptionHandler
 from repro.core.state import train_meta
 
@@ -405,7 +406,8 @@ def resume(root, *, target_struct=None, shardings=None, mesh=None,
         if lazy:
             verify_digest = False     # nothing to digest until leaves arrive
         if verify_digest and rec.state_digest:
-            got = tree_digest(pairs)   # raw decoded bytes, pre-cast/place
+            # raw decoded bytes, pre-cast/place, in the recorded scheme
+            got = tree_digest_like(pairs, rec.state_digest)
             digest_ok = got == rec.state_digest
             if not digest_ok:
                 raise CorruptionError(man["image_id"],

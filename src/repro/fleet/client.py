@@ -27,7 +27,7 @@ from repro.api.config import SessionConfig
 from repro.api.requests import DumpRequest, MigrateRequest, RestoreRequest
 from repro.api.session import CheckpointSession
 from repro.core.dump import flatten_with_paths
-from repro.core.integrity import tree_digest
+from repro.core.integrity import tree_digest_like
 from repro.core.remote import TransferError
 from repro.fleet.messages import (DrainAck, DrainCommand, ErrorReply,
                                   Heartbeat, RestoreAck)
@@ -147,7 +147,10 @@ class FleetClient:
         if self.on_restore:
             self.on_restore(res)
         after = dict(getattr(tier, "stats", {}))
-        digest = tree_digest(flatten_with_paths(res.state))
+        # in the scheme of the image's record, which the coordinator
+        # compares this ack with
+        digest = tree_digest_like(flatten_with_paths(res.state),
+                                  res.migration.state_digest)
         return RestoreAck(
             job_id=self.job_id, image_id=res.image_id, step=res.step,
             host=self.host, digest_verified=res.digest_verified,

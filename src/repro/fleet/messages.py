@@ -78,7 +78,8 @@ class DrainAck(WireRecord):
 @dataclasses.dataclass(frozen=True)
 class RestoreAck(WireRecord):
     """A restore landed on ``host``. ``state_digest`` is recomputed from
-    the restored leaves (integrity.tree_digest), so coordinator-side
+    the restored leaves in the scheme of the image's recorded digest
+    (integrity.tree_digest_like), so coordinator-side
     bit-identity verification needs only wire data; ``digest_verified``
     echoes the session's own manifest check.
 

@@ -76,6 +76,11 @@ def test_migrate_and_restore_nest_across_the_pools(tmp_path):
         by_parent[s["parent"]].append(s["name"])
     assert by_parent[mig["id"]] == ["ckpt.capture", "ckpt.tree_digest",
                                     "ckpt.save"]
+    digest = one("ckpt.tree_digest")
+    assert digest["attrs"]["blocks"] == 4
+    assert digest["counters"]["digest_block"]["count"] == 4
+    assert digest["counters"]["digest_block"]["bytes"] == \
+        digest["attrs"]["bytes"]
     assert by_parent[dump["id"]] == ["ckpt.capture", "ckpt.dump.chunks"]
     assert "ckpt.gc" in by_parent[save["id"]]
     # children lie inside their parents on one clock
