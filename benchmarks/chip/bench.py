@@ -5,7 +5,8 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric lives in a file of its own under this directory and is found by the
 name ``BENCHMARK.json`` gives it:
 
-  configs/<config>.json   sizes as run, with the published source
+  configs/<config>.json   sizes as run, with the published source, naming
+                          its architecture's module ``references/<name>.py``
   traffic/<traffic>.json  parameters read by the generator ``kinds/<kind>.py``
   limits/<cell>.json      the limit of each number ``correct`` compares
   metrics/<metric>.py     a reader: ``read(rec) -> float | None``
@@ -48,6 +49,7 @@ def load_cell(spec: dict, name: str) -> dict:
     cell = dict(cells[name])
     conf = {c["name"]: c for c in spec["configs"]}[cell["config"]]
     cell["config_file"] = json.loads((ROOT / conf["file"]).read_text())
+    reference.module(cell["config_file"], f"configuration {conf['name']!r}")
     cell["traffic_file"] = json.loads(
         (HERE / "traffic" / f"{cell['traffic']}.json").read_text())
     cell["limits"] = json.loads((HERE / "limits" / f"{name}.json").read_text())
@@ -63,32 +65,20 @@ def load_module(path: Path):
 
 
 # ------------------------------------------------------------------ model
-def model_config(conf: dict):
+def model_config(conf: dict, model):
     """The program's ModelConfig for a configuration file: the program's
-    config of the architecture with every size the file states, checked
-    against what the file says the reference runs (``as_run``)."""
+    config of the architecture with every size the file states
+    (`model.program_fields`, `model` the file's reference module), checked
+    key by key against what the file says the reference runs (``as_run``)."""
     from repro import configs
-    cfg = configs.get_config(conf["program_arch"]).replace(
-        num_layers=conf["num_hidden_layers"], vocab_size=conf["vocab_size"],
-        d_model=conf["hidden_size"], num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim", 0), d_ff=conf["intermediate_size"],
-        num_experts=conf.get("num_local_experts", 0),
-        experts_per_token=conf.get("num_experts_per_tok", 0),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        rope_theta=float(conf["rope_theta"]), norm_eps=float(conf["rms_norm_eps"]))
-    run = conf["as_run"]
-    said = {"vocab_padded": cfg.padded_vocab, "qk_norm": cfg.qk_norm,
-            "embedding_scale": cfg.d_model ** 0.5 if cfg.tie_embeddings
-            else 1.0}
-    if cfg.num_experts:
-        said.update(capacity_factor=cfg.capacity_factor,
-                    router_aux_loss_coef=cfg.router_aux_coef)
-    for key, value in said.items():
-        if run.get(key) != value:
-            raise ValueError(f"{conf['program_arch']}: as_run {key}="
-                             f"{run.get(key)} in the configuration file, "
-                             f"{value} in the program")
+    fields = model.program_fields(conf)
+    cfg = configs.get_config(fields["name"]).replace(**fields)
+    run, said = conf["as_run"], model.as_run_readings(cfg)
+    for key in sorted(set(run) | set(said)):
+        if run.get(key) != said.get(key):
+            raise ValueError(f"{fields['name']}: as_run {key}={run.get(key)} "
+                             f"in the configuration file, {said.get(key)} in "
+                             f"the program")
     return cfg
 
 
@@ -372,7 +362,8 @@ class Job:
         from repro.optim import OptConfig
         from repro.training.train_loop import abstract_train_state
         self.conf, self.traffic, self.rec = conf, traffic, rec
-        self.cfg = model_config(conf)
+        self.model = reference.module(conf)
+        self.cfg = model_config(conf, self.model)
         self.codec = codec or {}
         self.gb, self.seq = traffic["global_batch"], traffic["seq_len"]
         self.rng, self.key = seeds(seed)
@@ -384,7 +375,7 @@ class Job:
                                  self.cfg.vocab_size, self.key)
             self.ds = self.corpus.dataset()
         self.abstract = abstract_train_state(self.lm)
-        ref = jax.eval_shape(lambda: reference.init_params(
+        ref = jax.eval_shape(lambda: self.model.init_params(
             conf, jax.random.PRNGKey(0)))
         if ref != self.abstract["params"]:
             raise ValueError("the reference's weight tree differs from the "
@@ -398,7 +389,7 @@ class Job:
                 sharding=self.batch_sharding)}
             self.step = compile_step(self.lm, self.opt, self.struct, batch)
             self.fp = compile_fingerprint(self.struct)
-            self.init = reference.jit_init(conf)
+            self.init = reference.jit_init(self.model, conf)
             self.norms = jax.jit(reference.leaf_norms)
             self.change = jax.jit(lambda p, p0: reference.leaf_norms(
                 jax.tree.map(jax.numpy.subtract, p, p0)))
@@ -485,8 +476,9 @@ class Job:
         import jax
         batches = [self.corpus.batch(g, self.gb, self.seq)
                    for g in range(reference.STEPS)]
-        return reference.train(self.conf, OPT, jax.random.PRNGKey(self.key),
-                               batches, mode=mode, rows=rows)
+        return reference.train(self.model, self.conf, OPT,
+                               jax.random.PRNGKey(self.key), batches,
+                               mode=mode, rows=rows)
 
     def batch_mismatches(self) -> int:
         """Steps whose batch differs from the reference stream."""

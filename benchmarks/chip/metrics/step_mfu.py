@@ -1,5 +1,5 @@
 """Model FLOPs of the window's steps over chips x bf16 peak x the steps'
-wall time, in percent (flops.py counts them from the shapes)."""
+wall time, in percent (flops.py, from the configuration's sizes)."""
 import flops
 import peaks
 
@@ -11,7 +11,7 @@ def read(rec):
     if not steps:
         return None
     job = rec["job"]
-    work = flops.train_flops_per_token(job.cfg, job.seq) \
+    work = flops.train_flops_per_token(job.model, job.conf, job.seq) \
         * len(steps) * job.gb * job.seq
     peak = peaks.peak(rec["device"]["kind"])["bf16_flops_per_s"]
     wall = sum(s["t1"] - s["t0"] for s in steps)

@@ -1,20 +1,21 @@
-"""Plain reference of the training step the benchmark drives.
+"""The shared part of the plain references of the training step.
 
-A straightforward jnp implementation of what a configuration file under
-``configs/`` states, written apart from the program and importing nothing
-of it: the decoder (RMSNorm, rotary attention with grouped KV heads and
-optional qk-norm, a SwiGLU MLP or a top-k mixture of experts with capacity
-and a load-balancing loss), the cross-entropy over the real vocabulary,
-global-norm clipping, warmup-cosine learning rate and AdamW. Float32
-weights and optimizer state, bfloat16 matmul operands with float32
-accumulation, as the file's ``state`` says.
+Each configuration file names its architecture's module under
+``references/`` (``"reference": "<module>"``); that module holds what is
+particular to the architecture (the program's config from the file's keys,
+the weights, the loss, the FLOP count) and builds on the pieces here, which
+every architecture shares: bfloat16 matmuls with float32 accumulation (and
+the int8 control), RMSNorm, rotary embedding, blocked causal attention, a
+SwiGLU MLP, the chunked cross-entropy over the real vocabulary, global-norm
+clipping, warmup-cosine learning rate and AdamW. Nothing here or there
+imports the program.
 
-The benchmark makes the job's initial weights with ``init_params`` from the
-seed, so the reference starts from the same weights without taking them
-from the program. ``train`` runs the first steps and returns what
-``gaps`` compares with the program's readings of the same steps: each
-step's loss, each leaf's norm of the first (clipped) gradient, and each
-leaf's norm of the parameters' change over the steps.
+The benchmark makes the job's initial weights with the module's
+``init_params`` from the seed, so the reference starts from the same
+weights without taking them from the program. ``train`` runs the first
+steps and returns what ``gaps`` compares with the program's readings of the
+same steps: each step's loss, each leaf's norm of the first (clipped)
+gradient, and each leaf's norm of the parameters' change over the steps.
 
 ``mode="int8"`` is the control: every weight matmul with both operands
 rounded to int8 (one scale per tensor), the step below bfloat16.
@@ -22,7 +23,9 @@ rounded to int8 (one scale per tensor), the step below bfloat16.
 from __future__ import annotations
 
 import functools
-from types import SimpleNamespace
+import importlib
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -30,61 +33,34 @@ import numpy as np
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 STEPS = 3
+MODULES = Path(__file__).resolve().parent / "references"
 
 
-def dims(conf: dict) -> SimpleNamespace:
-    run = conf["as_run"]
-    d, h = conf["hidden_size"], conf["num_attention_heads"]
-    return SimpleNamespace(
-        d=d, h=h, kv=conf["num_key_value_heads"],
-        hd=conf.get("head_dim", d // h), ff=conf["intermediate_size"],
-        layers=conf["num_hidden_layers"], vocab=conf["vocab_size"],
-        vpad=run["vocab_padded"], tied=bool(conf["tie_word_embeddings"]),
-        theta=float(conf["rope_theta"]), eps=float(conf["rms_norm_eps"]),
-        qk_norm=bool(run["qk_norm"]), embed_scale=float(run["embedding_scale"]),
-        experts=conf.get("num_local_experts", 0),
-        topk=conf.get("num_experts_per_tok", 0),
-        capacity=float(run.get("capacity_factor", 0.0)),
-        aux_coef=float(run.get("router_aux_loss_coef", 0.0)))
+def module(conf: dict, name: str = "the configuration"):
+    """The architecture's module that configuration file `conf` names
+    (``references/<conf["reference"]>.py``). `name` says which file, for
+    the message when it names none or one that is not there."""
+    ref = conf.get("reference")
+    if not ref:
+        raise ValueError(f"{name} names no reference module: give it a key "
+                         f"\"reference\" that names one of "
+                         f"{sorted(p.stem for p in MODULES.glob('*.py'))}")
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", str(ref)) \
+            or not (MODULES / f"{ref}.py").is_file():
+        raise ValueError(f"{name} names reference module {ref!r}, which is "
+                         f"not under {MODULES.name}/")
+    return importlib.import_module(f"references.{ref}")
 
 
 # ------------------------------------------------------------------ weights
-def param_specs(conf: dict) -> dict:
-    """The weight tree: each leaf (shape, init std), std None for ones."""
-    m = dims(conf)
-    L, d, ff = m.layers, m.d, m.ff
-    attn = {"wq": ((L, d, m.h, m.hd), d ** -0.5),
-            "wk": ((L, d, m.kv, m.hd), d ** -0.5),
-            "wv": ((L, d, m.kv, m.hd), d ** -0.5),
-            "wo": ((L, m.h, m.hd, d), (m.h * m.hd) ** -0.5)}
-    if m.qk_norm:
-        attn["q_norm"] = ((L, m.hd), None)
-        attn["k_norm"] = ((L, m.hd), None)
-    block = {"ln1": ((L, d), None), "ln2": ((L, d), None), "attn": attn}
-    if m.experts:
-        E = m.experts
-        block["moe"] = {"router": ((L, d, E), d ** -0.5),
-                        "w_up": ((L, E, d, ff), d ** -0.5),
-                        "w_gate": ((L, E, d, ff), d ** -0.5),
-                        "w_down": ((L, E, ff, d), ff ** -0.5)}
-    else:
-        block["mlp"] = {"w_up": ((L, d, ff), d ** -0.5),
-                        "w_gate": ((L, d, ff), d ** -0.5),
-                        "w_down": ((L, ff, d), ff ** -0.5)}
-    embed = {"tok": ((m.vpad, d), d ** -0.5)}
-    if not m.tied:
-        embed["head"] = ((d, m.vpad), d ** -0.5)
-    return {"embed": embed, "final_norm": ((d,), None),
-            "stack": {"b0": block}}
-
-
 def _is_spec(x) -> bool:
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
 
 
-def init_params(conf: dict, key):
-    """Float32 weights from a PRNG key: normal(0, std) or ones."""
-    specs = param_specs(conf)
+def init_from_specs(specs, key):
+    """Float32 weights of a tree of (shape, std) leaves from a PRNG key:
+    normal(0, std), the i-th leaf in tree order from fold_in(key, i), or
+    ones where std is None."""
     flat, tree = jax.tree_util.tree_flatten(specs, is_leaf=_is_spec)
     out = []
     for i, (shape, std) in enumerate(flat):
@@ -96,10 +72,10 @@ def init_params(conf: dict, key):
     return jax.tree_util.tree_unflatten(tree, out)
 
 
-def jit_init(conf: dict):
-    """init_params compiled for `conf`: the one program that makes the
-    initial weights, for the job and for the reference alike."""
-    return jax.jit(functools.partial(init_params, conf))
+def jit_init(model, conf: dict):
+    """`model`'s init_params compiled for `conf`: the one program that makes
+    the initial weights, for the job and for the reference alike."""
+    return jax.jit(functools.partial(model.init_params, conf))
 
 
 def leaf_norms(tree):
@@ -108,7 +84,7 @@ def leaf_norms(tree):
                       for x in jax.tree.leaves(tree)])
 
 
-# -------------------------------------------------------------------- model
+# ------------------------------------------------------------ model pieces
 def _int8(x):
     """Round to int8 with one scale per tensor; the gradient passes through."""
     s = jnp.maximum(jnp.max(jnp.abs(x.astype(F32))), 1e-30) / 127.0
@@ -116,20 +92,21 @@ def _int8(x):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _mm(eq, a, w, mode):
+def mm(eq, a, w, mode):
+    """A weight matmul: bfloat16 operands (int8 in the control)."""
     a, w = a.astype(BF16), w.astype(BF16)
     if mode == "int8":
         a, w = _int8(a), _int8(w)
     return jnp.einsum(eq, a, w)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     xf = x.astype(F32)
     xf = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
     return (xf * scale.astype(F32)).astype(x.dtype)
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """Rotate the two halves of each head by position x frequency."""
     S, hd = x.shape[1], x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
@@ -140,7 +117,7 @@ def _rope(x, theta):
                            -1).astype(x.dtype)
 
 
-def _attention(q, k, v, block=256):
+def attention(q, k, v, block=256):
     """Causal softmax attention, one block of queries at a time."""
     B, S, H, hd = q.shape
     k = jnp.repeat(k, H // k.shape[2], axis=2)
@@ -164,50 +141,16 @@ def _attention(q, k, v, block=256):
     return jnp.moveaxis(out, 0, 1).reshape(B, nb * block, H, hd)[:, :S]
 
 
-def _mlp(p, h, mode):
-    up = _mm("bsd,df->bsf", h, p["w_up"], mode)
-    gate = jax.nn.silu(_mm("bsd,df->bsf", h, p["w_gate"], mode))
-    return _mm("bsf,fd->bsd", gate * up, p["w_down"], mode)
+def swiglu(p, h, mode):
+    up = mm("bsd,df->bsf", h, p["w_up"], mode)
+    gate = jax.nn.silu(mm("bsd,df->bsf", h, p["w_gate"], mode))
+    return mm("bsf,fd->bsd", gate * up, p["w_down"], mode)
 
 
-def _moe(p, h, m, mode, block=2048):
-    """Top-k routing over all tokens of the batch; an expert keeps its
-    first `capacity` assignments in (token, k) order and drops the rest.
-    Every expert runs on every token, weighted by its kept gate."""
-    B, S, d = h.shape
-    T, E, K = B * S, m.experts, m.topk
-    xt = h.reshape(T, d)
-    probs = jax.nn.softmax(_mm("td,de->te", xt, p["router"], mode)
-                           .astype(F32), -1)
-    gate, idx = jax.lax.top_k(probs, K)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
-    cap = max(8, int(T * K * m.capacity // E))
-    hit = (idx.reshape(T * K)[:, None] == jnp.arange(E)).astype(jnp.int32)
-    ahead = ((jnp.cumsum(hit, 0) - hit) * hit).sum(-1)   # place in its expert
-    kept = (gate.reshape(T * K) * (ahead < cap)).astype(BF16)
-    w = (hit.astype(BF16) * kept[:, None]).reshape(T, K, E).sum(1)   # [T, E]
-    nb = -(-T // block)
-    pad = ((0, nb * block - T), (0, 0))
-    xs = (jnp.pad(xt, pad).reshape(nb, block, d),
-          jnp.pad(w, pad).reshape(nb, block, E))
-
-    @jax.checkpoint
-    def one(args):
-        x, wb = args
-        up = _mm("td,edf->tef", x, p["w_up"], mode)
-        gt = jax.nn.silu(_mm("td,edf->tef", x, p["w_gate"], mode))
-        y = _mm("tef,efd->ted", gt * up, p["w_down"], mode)
-        return jnp.einsum("ted,te->td", y, wb)
-
-    y = jax.lax.map(one, xs).reshape(nb * block, d)[:T].reshape(B, S, d)
-    counts = hit.sum(0).astype(F32)
-    frac = counts / jnp.maximum(counts.sum(), 1.0)
-    return y, E * jnp.sum(frac * probs.mean(0))
-
-
-def _cross_entropy(x, labels, head, m, mode, chunk=512):
-    """Mean next-token loss; logits over the padded vocabulary with the
-    padding masked, one chunk of positions at a time."""
+def cross_entropy(x, labels, head, vocab, mode, chunk=512):
+    """Mean next-token loss; logits over the padded vocabulary (the head's
+    columns) with the rows past `vocab` masked, one chunk of positions at
+    a time."""
     B, T, d = x.shape
     n = -(-T // chunk)
     pad = n * chunk - T
@@ -216,54 +159,18 @@ def _cross_entropy(x, labels, head, m, mode, chunk=512):
     ls = jnp.moveaxis(jnp.pad(labels, ((0, 0), (0, pad)))
                       .reshape(B, n, chunk), 1, 0)
     valid = (jnp.arange(n * chunk) < T).reshape(n, chunk).astype(F32)
-    real = jnp.arange(m.vpad) < m.vocab
+    real = jnp.arange(head.shape[-1]) < vocab
 
     @jax.checkpoint
     def one(args):
         xc, lc, vc = args
-        logits = _mm("bcd,dv->bcv", xc, head, mode).astype(F32)
+        logits = mm("bcd,dv->bcv", xc, head, mode).astype(F32)
         logits = jnp.where(real, logits, -1e30)
         lse = jax.scipy.special.logsumexp(logits, -1)
         gold = jnp.take_along_axis(logits, lc[..., None], -1)[..., 0]
         return jnp.sum((lse - gold) * vc[None])
 
     return jnp.sum(jax.lax.map(one, (xs, ls, valid))) / (B * T)
-
-
-def loss_fn(params, tokens, conf: dict, mode: str = "bf16"):
-    """tokens [B, S+1]: the model reads tokens[:, :S+1] and predicts
-    tokens[:, 1:]; the loss adds the mean load-balancing term of the
-    expert layers."""
-    m = dims(conf)
-    emb = jnp.take(params["embed"]["tok"].astype(BF16), tokens, axis=0)
-    if m.embed_scale != 1.0:
-        emb = emb * jnp.asarray(m.embed_scale, BF16)
-
-    @jax.checkpoint
-    def layer(x, p):
-        h = _rms(x, p["ln1"], m.eps)
-        a = p["attn"]
-        q = _mm("bsd,dhk->bshk", h, a["wq"], mode)
-        k = _mm("bsd,dhk->bshk", h, a["wk"], mode)
-        v = _mm("bsd,dhk->bshk", h, a["wv"], mode)
-        if m.qk_norm:
-            q, k = _rms(q, a["q_norm"], m.eps), _rms(k, a["k_norm"], m.eps)
-        q, k = _rope(q, m.theta), _rope(k, m.theta)
-        x = x + _mm("bshk,hkd->bsd", _attention(q, k, v), a["wo"], mode)
-        h = _rms(x, p["ln2"], m.eps)
-        if m.experts:
-            o, aux = _moe(p["moe"], h, m, mode)
-        else:
-            o, aux = _mlp(p["mlp"], h, mode), jnp.zeros((), F32)
-        return x + o, aux
-
-    x, aux = jax.lax.scan(layer, emb, params["stack"]["b0"])
-    x = _rms(x, params["final_norm"], m.eps)
-    head = params["embed"]["tok"].T if m.tied else params["embed"]["head"]
-    loss = _cross_entropy(x[:, :-1], tokens[:, 1:], head, m, mode)
-    if m.experts:
-        loss = loss + m.aux_coef * jnp.sum(aux) / m.layers
-    return loss
 
 
 # ---------------------------------------------------------------- optimizer
@@ -275,8 +182,8 @@ def _lr(t, opt: dict):
     return jnp.where(t < opt["warmup_steps"], warm, cos)
 
 
-def _step(params, m, v, t, tokens, conf, opt, mode):
-    loss, g = jax.value_and_grad(loss_fn)(params, tokens, conf, mode)
+def _step(params, m, v, t, tokens, loss, opt):
+    loss, g = jax.value_and_grad(loss)(params, tokens)
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(g)))
     scale = jnp.minimum(1.0, opt["clip_norm"] / jnp.maximum(gnorm, 1e-12))
     g = jax.tree.map(lambda x: x * scale, g)
@@ -294,18 +201,24 @@ def _step(params, m, v, t, tokens, conf, opt, mode):
     return params, m, v, loss, leaf_norms(g)
 
 
-def train(conf: dict, opt: dict, key, batches, *, mode: str = "bf16",
+def step_fn(model, conf: dict, opt: dict, mode: str = "bf16"):
+    """One AdamW step of `model`'s loss, to be jitted:
+    (params, m, v, t, tokens) -> (params, m, v, loss, gradient norms)."""
+    return functools.partial(_step, opt=opt, loss=functools.partial(
+        model.loss_fn, conf=conf, mode=mode))
+
+
+def train(model, conf: dict, opt: dict, key, batches, *, mode: str = "bf16",
           rows: int | None = None) -> dict:
-    """The first STEPS steps from init_params(key) on `batches` (numpy
-    [B, S+1] each; `rows` keeps the first rows only). Returns each step's
-    loss, each leaf's norm of the first clipped gradient and of the
-    parameters' change over the steps."""
-    init = jit_init(conf)
+    """The first STEPS steps of `model` from its init_params(key) on
+    `batches` (numpy [B, S+1] each; `rows` keeps the first rows only).
+    Returns each step's loss, each leaf's norm of the first clipped
+    gradient and of the parameters' change over the steps."""
+    init = jit_init(model, conf)
     params = init(key)
     zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p))
     m, v = zeros(params), zeros(params)
-    step = jax.jit(functools.partial(_step, conf=conf, opt=opt, mode=mode),
-                   donate_argnums=(0, 1, 2))
+    step = jax.jit(step_fn(model, conf, opt, mode), donate_argnums=(0, 1, 2))
     losses, grad = [], None
     for i, b in enumerate(batches[:STEPS]):
         b = np.asarray(b)[:rows] if rows else np.asarray(b)
@@ -342,4 +255,3 @@ def gaps(got: dict, ref: dict) -> dict:
         "grad_gap": worst(got["grad"], ref["grad"], slice(None)),
         "change_gap": worst(got["change"], ref["change"], moved),
     }
-

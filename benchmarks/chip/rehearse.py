@@ -14,7 +14,6 @@ that passes here is not a chip run.
 from __future__ import annotations
 
 import argparse
-import functools
 import os
 import sys
 import time
@@ -33,8 +32,9 @@ def rehearse(cell: dict, batch: int, devices) -> dict:
     from repro.models.model import LM
     from repro.optim import OptConfig
     from repro.training.train_loop import abstract_train_state
-    t = cell["traffic_file"]
-    lm = LM(bench.model_config(cell["config_file"]))
+    t, conf = cell["traffic_file"], cell["config_file"]
+    model = reference.module(conf)
+    lm = LM(bench.model_config(conf, model))
     _, sps, bsp = bench.layout(lm, devices)
     struct = bench.with_shardings(abstract_train_state(lm), sps)
     tokens = jax.ShapeDtypeStruct((batch, t["seq_len"] + 1),
@@ -52,9 +52,8 @@ def rehearse(cell: dict, batch: int, devices) -> dict:
     params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=sps["params"]["final_norm"]),
         struct["params"])
-    ref = jax.jit(functools.partial(
-        reference._step, conf=cell["config_file"], opt=bench.OPT,
-        mode="bf16"), donate_argnums=(0, 1, 2))
+    ref = jax.jit(reference.step_fn(model, conf, bench.OPT),
+                  donate_argnums=(0, 1, 2))
     out["reference"] = memory(ref.lower(
         params, params, params, jax.ShapeDtypeStruct((), jax.numpy.int32),
         tokens).compile(), t0)

@@ -9,7 +9,8 @@ traffic file; the traffic's ``kind`` names the generator under ``kinds/``.
 Set-up makes the job from the seed (corpus, state on the device, step
 program from the compile cache) and trains its first steps, then the window
 runs for ``--seconds``. Once it has closed and the state is freed, the
-plain reference (``reference.py``) follows the first steps and the
+plain reference (the module under ``references/`` that the configuration
+file names, with ``reference.py``) follows the first steps and the
 program's readings of them are compared with it.
 With ``--trace 0`` the result carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from spans and a profiler trace of

@@ -14,15 +14,17 @@ import control
 import reference
 import run
 
-QWEN = {"program_arch": "qwen3-8b", "hidden_size": 64, "num_attention_heads": 4,
+QWEN = {"reference": "decoder", "program_arch": "qwen3-8b",
+        "hidden_size": 64, "num_attention_heads": 4,
         "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
         "num_hidden_layers": 2, "vocab_size": 500, "rope_theta": 1e6,
         "rms_norm_eps": 1e-6, "tie_word_embeddings": False,
         "as_run": {"vocab_padded": 512, "qk_norm": True,
                    "embedding_scale": 1.0}}
-GRANITE = {"program_arch": "granite-moe-3b-a800m", "hidden_size": 64,
-           "num_attention_heads": 4, "num_key_value_heads": 2,
-           "intermediate_size": 32, "num_hidden_layers": 2,
+GRANITE = {"reference": "decoder", "program_arch": "granite-moe-3b-a800m",
+           "hidden_size": 64, "num_attention_heads": 4,
+           "num_key_value_heads": 2, "intermediate_size": 32,
+           "num_hidden_layers": 2,
            "vocab_size": 515, "num_local_experts": 8, "num_experts_per_tok": 2,
            "rope_theta": 1e4, "rms_norm_eps": 1e-6, "tie_word_embeddings": True,
            "as_run": {"vocab_padded": 768, "qk_norm": False,
@@ -165,6 +167,7 @@ def test_reference_weight_tree_matches_the_program():
     import jax
     from repro.models.model import LM
     for conf in (QWEN, GRANITE):
-        got = jax.eval_shape(lambda c=conf: reference.init_params(
+        model = reference.module(conf)
+        got = jax.eval_shape(lambda c=conf: model.init_params(
             c, jax.random.PRNGKey(0)))
-        assert got == LM(bench.model_config(conf)).abstract()
+        assert got == LM(bench.model_config(conf, model)).abstract()

@@ -1,15 +1,17 @@
 """The model-FLOP count against hand counts, and the peaks table."""
+from pathlib import Path
+
 import pytest
 
 import bench
 import flops
 import peaks
+from references import decoder
 
 
 def conf(arch, layers):
     """The configuration file of `arch` with `layers` layers."""
     import json
-    from pathlib import Path
     name = {"qwen3-8b": "qwen3-8b-1l", "granite-moe-3b-a800m":
             "granite-moe-3b-2l"}[arch]
     path = Path(bench.HERE) / "configs" / f"{name}.json"
@@ -36,9 +38,9 @@ GRANITE = dict(d=1536, H=24, KV=8, hd=64, ff=512, V=49155, E=40, K=8)
     ("granite-moe-3b-a800m", 2, 49155, 4096, hand(2, S=4096, **GRANITE)),
 ])
 def test_train_flops_match_hand_count(arch, layers, vocab, seq, want):
-    cfg = bench.model_config(dict(conf(arch, layers), vocab_size=vocab))
-    assert flops.train_flops_per_token(cfg, seq) == pytest.approx(3 * want,
-                                                                  rel=1e-12)
+    c = dict(conf(arch, layers), vocab_size=vocab)
+    assert flops.train_flops_per_token(decoder, c, seq) == pytest.approx(
+        3 * want, rel=1e-12)
 
 
 def test_hand_counts_in_numbers():
@@ -54,13 +56,32 @@ def test_hand_counts_in_numbers():
 
 
 def test_routed_experts_only_and_no_embedding_lookup():
-    cfg = bench.model_config(conf("granite-moe-3b-a800m", 1))
-    more = cfg.replace(num_experts=80)   # unrouted experts add only router
-    assert flops.forward_flops_per_token(more, 8) \
-        - flops.forward_flops_per_token(cfg, 8) == 2 * 1536 * 40
+    c = conf("granite-moe-3b-a800m", 1)
+    more = dict(c, num_local_experts=80)   # unrouted experts add only router
+    assert decoder.forward_flops_per_token(more, 8) \
+        - decoder.forward_flops_per_token(c, 8) == 2 * 1536 * 40
 
 
 def test_peaks_known_and_unknown():
     assert peaks.peak("TPU v5 lite")["bf16_flops_per_s"] == 197e12
     with pytest.raises(KeyError, match="no published peaks"):
         peaks.peak("cpu")
+
+
+@pytest.mark.parametrize("name", ["qwen3-8b-1l", "granite-moe-3b-2l"])
+def test_count_matches_the_count_from_the_program_config(name):
+    """The module's count from the file's keys equals the same formula
+    over the program's config built from that file (what step_mfu counted
+    before the count moved into the module)."""
+    import json
+    c = json.loads((Path(bench.HERE) / "configs" / f"{name}.json")
+                   .read_text())
+    cfg = bench.model_config(c, decoder)
+    assert cfg.glu
+    d, hd = cfg.d_model, cfg.resolved_head_dim
+    q, kv = cfg.num_heads * hd, cfg.num_kv_heads * hd
+    E, K = cfg.num_experts, cfg.experts_per_token
+    mlp = (2 * d * E + K * 3 * 2 * d * cfg.d_ff) if E else 3 * 2 * d * cfg.d_ff
+    want = cfg.num_layers * (2 * d * (q + 2 * kv) + 2 * q * d
+                             + 2 * q * 4097 + mlp) + 2 * d * cfg.vocab_size
+    assert decoder.forward_flops_per_token(c, 4096) == want
